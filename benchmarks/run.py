@@ -13,8 +13,8 @@
   serving           — beyond-paper: continuous vs static batching under
                       a mixed-arrival trace (tok/s, pool occupancy)
 
-Host wall-times are ordering-only (no TPU in this container); the graded
-performance numbers are the dry-run roofline terms in EXPERIMENTS.md.
+Host wall-times off the chip are ordering-only: a CPU run measures the
+XLA CPU backend or the Pallas interpreter, never the TPU.
 """
 from __future__ import annotations
 
@@ -46,6 +46,8 @@ def main() -> None:
     # strip the selector but forward flags (--smoke/--json) to the modules'
     # own argparse (benchmarks.common.bench_options)
     sys.argv = sys.argv[:1] + rest
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name in MODULES:
         if only and only != name:
             continue

@@ -19,8 +19,9 @@ Modes (env var ``REPRO_TUNE``):
                  kernel executions on the current backend, store the winner
                  in the cache, and use it from then on.
 
-The cache lives at ``$REPRO_TUNE_CACHE`` (default
-``~/.cache/repro/gemm_tune.json``); measured entries are keyed by
+The user cache is the file ``$REPRO_TUNE_CACHE`` names, read and written
+only when that variable is set (``full`` requires it) — unset, plan
+selection reads nothing outside the checkout; measured entries are keyed by
 ``MxKxN:dtype:backend`` (tuning on one backend never clobbers or shadows
 another's winners) and the unqualified ``MxKxN:dtype`` key is the
 hand-shipped-table escape hatch, trusted on any backend — a tuned serving
@@ -62,8 +63,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.tiling import (MXU_DIM, VMEM_BYTES, TilePlan, ceil_div,
-                               choose_plan, round_up)
+from repro.core.tiling import (MXU_DIM, VMEM_PLAN_BUDGET, TilePlan,
+                               ceil_div, choose_plan, round_up,
+                               vmem_tile_bytes)
 
 __all__ = [
     "Schedule",
@@ -137,7 +139,7 @@ class FusedPlan:
             block_k=None if self.schedule is Schedule.PANEL
             else self.block_k)
 
-    def fits_vmem(self, budget: int = VMEM_BYTES,
+    def fits_vmem(self, budget: int = VMEM_PLAN_BUDGET,
                   out_bytes: int = 2) -> bool:
         return self.footprint(out_bytes) <= budget
 
@@ -153,14 +155,15 @@ def tune_mode() -> str:
     if mode not in _VALID_MODES:
         raise ValueError(
             f"{TUNE_ENV} must be one of {_VALID_MODES}, got {mode!r}")
+    if mode == "full" and cache_path() is None:
+        raise ValueError(f"{TUNE_ENV}=full needs {CACHE_ENV}: the file the "
+                         "measured plans are written to")
     return mode
 
 
-def cache_path() -> str:
-    return os.environ.get(
-        CACHE_ENV,
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "gemm_tune.json"))
+def cache_path() -> str | None:
+    """The user tune cache, or None when ``REPRO_TUNE_CACHE`` is unset."""
+    return os.environ.get(CACHE_ENV) or None
 
 
 def seed_table_path() -> str:
@@ -207,7 +210,8 @@ def load_cache() -> dict[str, dict]:
     if _mem_cache is not None and _mem_cache_file == state:
         return _mem_cache
     table = _read_table(seed_table_path()) if _seed_enabled() else {}
-    table.update(_read_table(path))
+    if path is not None:
+        table.update(_read_table(path))
     _mem_cache = table
     _mem_cache_file = state
     return table
@@ -217,6 +221,8 @@ def _store(key: str, entry: dict) -> None:
     """Read-merge-write so concurrent tuners lose at most their own entry."""
     global _mem_cache, _mem_cache_file
     path = cache_path()
+    if path is None:
+        raise ValueError(f"tuning writes to {CACHE_ENV}, which is unset")
     table = _read_table(path)      # persist only user entries, not the seed
     table[key] = entry
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -248,8 +254,11 @@ def reset_cache_state() -> None:
 
 def clear_cache() -> None:
     reset_cache_state()
+    path = cache_path()
+    if path is None:
+        return
     try:
-        os.unlink(cache_path())
+        os.unlink(path)
     except OSError:
         pass
 
@@ -266,8 +275,8 @@ def _plan_from_entry(m: int, k: int, n: int, out_bytes: int,
     except (KeyError, TypeError, ValueError):
         return None
     # hold cached (possibly hand-shipped / version-skewed) entries to the
-    # same half-VMEM headroom the tuner's own candidates are generated under
-    return plan if plan.fits_vmem(VMEM_BYTES // 2) else None
+    # same VMEM budget the tuner's own candidates are generated under
+    return plan if plan.fits_vmem(VMEM_PLAN_BUDGET) else None
 
 
 def _measurement_backend(interpret: bool | None) -> str:
@@ -280,7 +289,7 @@ def _measurement_backend(interpret: bool | None) -> str:
 # Candidate generation — the analytic model seeds the search space
 # ---------------------------------------------------------------------------
 def candidate_plans(m: int, k: int, n: int, *, out_bytes: int = 2,
-                    vmem_budget: int = VMEM_BYTES // 2,
+                    vmem_budget: int = VMEM_PLAN_BUDGET,
                     max_candidates: int = 8) -> list[TilePlan]:
     """Feasible TilePlans around the analytic pick, analytic pick first.
 
@@ -414,7 +423,11 @@ def select_plan(m: int, k: int, n: int, *, out_dtype=jnp.bfloat16,
     if mode == "full":
         try:
             return tune(m, k, n, out_dtype=out_dtype, interpret=interpret)
-        except Exception as e:     # measurement must never take down a trace
+        except Exception as e:
+            # on the chip a kernel that fails to compile or run is a bug
+            # to surface; elsewhere measurement is advisory
+            if backend == "tpu":
+                raise
             warnings.warn(
                 f"REPRO_TUNE=full: measurement for ({m},{k},{n}) failed "
                 f"({type(e).__name__}: {e}); using the analytic plan")
@@ -426,22 +439,20 @@ def _fused_qkv_footprint(bm: int, bn: int, k: int, out_bytes: int,
                          block_k: int | None = None) -> int:
     """VMEM bytes of the fused QKV kernel under either schedule.
 
-    Panel (``block_k is None``): persistent A panel (bm, K) + three
-    double-buffered streamed weight blocks (K, bn) + three outputs.
-    K-split: A slab (bm, bk) and weight slabs (bk, bn) double-buffered, plus
-    three int32 accumulators carried across the K sweep.
+    Every block is double-buffered by the grid pipeline and padded to the
+    VMEM tiling (``tiling.vmem_tile_bytes``).  Panel (``block_k is
+    None``): the A panel (bm, K), three weight blocks (K, bn) and three
+    outputs.  K-split: the A slab (bm, bk) and weight slabs (bk, bn), plus
+    three int32 accumulators carried across the K sweep.  Both hold one
+    int32 product and its f32 dequant as live temporaries.
     """
-    if block_k is None or block_k >= k:
-        a = bm * k                      # int8 activation panel, resident
-        w = 3 * 2 * k * bn              # Wq/Wk/Wv, double-buffered
-        acc = 0                         # epilogue writes outputs directly
-    else:
-        a = 2 * bm * block_k            # A streamed in K slabs
-        w = 3 * 2 * block_k * bn
-        acc = 3 * bm * bn * 4           # int32 accumulator scratch x3
-    out = 3 * bm * bn * out_bytes
-    scales = (bm + 6 * bn) * 4
-    return a + w + out + acc + scales
+    bk = k if block_k is None or block_k >= k else block_k
+    blocks = (vmem_tile_bytes(bm, bk, 1) + 3 * vmem_tile_bytes(bk, bn, 1)
+              + 3 * vmem_tile_bytes(bm, bn, out_bytes)
+              + vmem_tile_bytes(bm, 1, 4) + 3 * vmem_tile_bytes(1, bn, 4))
+    acc = vmem_tile_bytes(bm, bn, 4)
+    scratch = 3 * acc if bk < k else 0
+    return 2 * blocks + 2 * acc + scratch
 
 
 def _block_caps(m: int, n: int) -> tuple[int, int]:
@@ -477,7 +488,7 @@ def _analytic_fused_plan(m: int, k: int, nq: int, nkv: int, *,
 
 def fused_candidate_plans(m: int, k: int, nq: int, nkv: int, *,
                           out_bytes: int = 2,
-                          vmem_budget: int = VMEM_BYTES // 2,
+                          vmem_budget: int = VMEM_PLAN_BUDGET,
                           max_candidates: int = 8) -> list[FusedPlan]:
     """Feasible FusedPlans across BOTH schedules, analytic pick first.
 
@@ -597,14 +608,14 @@ def _fused_plan_from_entry(m: int, k: int, nq: int, nkv: int,
         block_k = k                 # panel means the full contraction
     plan = FusedPlan(m, k, nq, nkv, block_m, block_n, block_k, sched)
     # hold cached (possibly hand-shipped / version-skewed) entries to the
-    # same half-VMEM headroom the tuner's own candidates are generated under
+    # same VMEM budget the tuner's own candidates are generated under
     return plan if plan.footprint(out_bytes) <= vmem_budget else None
 
 
 def select_fused_plan(m: int, k: int, nq: int, nkv: int, *,
                       out_dtype=jnp.bfloat16,
                       interpret: bool | None = None,
-                      vmem_budget: int = VMEM_BYTES // 2) -> FusedPlan:
+                      vmem_budget: int = VMEM_PLAN_BUDGET) -> FusedPlan:
     """Schedule-aware plan for the fused QKV projection.
 
     Lookup order under ``cached``/``full``: the extended fused key
@@ -640,7 +651,10 @@ def select_fused_plan(m: int, k: int, nq: int, nkv: int, *,
         try:
             return tune_fused(m, k, nq, nkv, out_dtype=out_dtype,
                               interpret=interpret)
-        except Exception as e:     # measurement must never take down a trace
+        except Exception as e:
+            # as in select_plan: on the chip a measurement failure raises
+            if backend == "tpu":
+                raise
             warnings.warn(
                 f"REPRO_TUNE=full: fused measurement for "
                 f"({m},{k},{nq}+{nkv}) failed ({type(e).__name__}: {e}); "
@@ -651,8 +665,8 @@ def select_fused_plan(m: int, k: int, nq: int, nkv: int, *,
 
 def select_fused_blocks(m: int, k: int, n: int, *, out_dtype=jnp.bfloat16,
                         interpret: bool | None = None,
-                        vmem_budget: int = VMEM_BYTES // 2) -> tuple[int,
-                                                                    int]:
+                        vmem_budget: int = VMEM_PLAN_BUDGET) -> tuple[int,
+                                                                       int]:
     """Back-compat shim: panel-only (block_m, block_n) for MHA (Nkv == Nq).
 
     Pre-schedule callers assume the panel-resident kernel, so a K-split pick

@@ -8,6 +8,12 @@ and MXU alignment; this module does the same budgeting analytically so that
   * ``benchmarks/tile_sweep.py`` can reproduce the paper's T∈{16,32,64} DSE
     as a block-shape sweep with predicted-vs-ideal roofline numbers,
   * tests can assert the invariants (footprint ≤ VMEM, full coverage).
+
+The footprint model counts what Pallas actually allocates: every input
+and output block double-buffered by the grid pipeline, each padded to the
+VMEM tiling, plus the kernel's int32/f32 temporaries.  Plans are budgeted
+against ``VMEM_PLAN_BUDGET``, a fixed share of the scoped-VMEM limit the
+GEMM kernels are compiled with (``VMEM_LIMIT_BYTES``).
 """
 from __future__ import annotations
 
@@ -18,8 +24,14 @@ PEAK_BF16_FLOPS = 197e12          # FLOP/s
 PEAK_INT8_OPS = 394e12            # int8 MAC*2/s (2x bf16 on the MXU)
 HBM_BW = 819e9                    # bytes/s
 ICI_BW = 50e9                     # bytes/s per link
-VMEM_BYTES = 128 * 1024 * 1024    # ~128 MiB usable VMEM per core
+VMEM_BYTES = 128 * 1024 * 1024    # physical VMEM per core
+# scoped-VMEM limit the GEMM kernels are compiled with
+# (pltpu.CompilerParams(vmem_limit_bytes=...)); the planner budgets three
+# quarters of it and leaves the rest to Mosaic's internal scratch
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+VMEM_PLAN_BUDGET = VMEM_LIMIT_BYTES * 3 // 4
 MXU_DIM = 128                     # systolic array edge (the paper's "32")
+LANES = 128                       # minor dim of the VMEM tiling
 
 
 def ceil_div(a: int, b: int) -> int:
@@ -28,6 +40,19 @@ def ceil_div(a: int, b: int) -> int:
 
 def round_up(x: int, m: int) -> int:
     return ceil_div(x, m) * m
+
+
+def sublane_rows(itemsize: int) -> int:
+    """Rows of one sublane tile of the (rows, 128) VMEM tiling: 8 rows of
+    32-bit words, so 16 for bf16 and 32 for int8."""
+    return 32 // itemsize
+
+
+def vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) block: the minor dim pads to 128
+    lanes, the second-minor to the dtype's sublane tile."""
+    return (round_up(rows, sublane_rows(itemsize)) * round_up(cols, LANES)
+            * itemsize)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,16 +84,21 @@ class TilePlan:
     # -- level-1 (VMEM) footprint ------------------------------------------
     @property
     def vmem_footprint(self) -> int:
-        a = self.block_m * self.block_k * self.a_bytes
-        b = self.block_k * self.block_n * self.b_bytes
-        out = self.block_m * self.block_n * self.out_bytes
-        acc = (self.block_m * self.block_n * self.acc_bytes
-               if self.k_steps > 1 else 0)
-        scales = (self.block_m + self.block_n) * 4
-        # double-buffering of the streamed operand (B) is the Pallas default
-        return a + 2 * b + out + acc + scales
+        """Bytes of VMEM the kernel allocates under this plan."""
+        bm, bn, bk = self.block_m, self.block_n, self.block_k
+        blocks = (vmem_tile_bytes(bm, bk, self.a_bytes)           # A
+                  + vmem_tile_bytes(bk, bn, self.b_bytes)         # B
+                  + vmem_tile_bytes(bm, bn, self.out_bytes)       # C
+                  + vmem_tile_bytes(bm, 1, 4)                     # row scales
+                  + 2 * vmem_tile_bytes(1, bn, 4))                # col scales, bias
+        # the grid pipeline double-buffers every block, the j-invariant A
+        # panel included; the int32 product and its f32 dequant are live
+        # temporaries, and the K-split schedule adds its accumulator
+        acc = vmem_tile_bytes(bm, bn, self.acc_bytes)
+        temps = 2 * acc + (acc if self.k_steps > 1 else 0)
+        return 2 * blocks + temps
 
-    def fits_vmem(self, budget: int = VMEM_BYTES) -> bool:
+    def fits_vmem(self, budget: int = VMEM_PLAN_BUDGET) -> bool:
         return self.vmem_footprint <= budget
 
     # -- reuse / traffic model (MAESTRO-style temporal reuse) ---------------
@@ -115,7 +145,7 @@ class TilePlan:
 
 def choose_plan(m: int, k: int, n: int, *,
                 out_bytes: int = 2,
-                vmem_budget: int = VMEM_BYTES // 2) -> TilePlan:
+                vmem_budget: int = VMEM_PLAN_BUDGET) -> TilePlan:
     """Pick block shapes: the paper's DSE, automated.
 
     Strategy (mirrors paper §5 "Tile size selection", with MXU=128 replacing

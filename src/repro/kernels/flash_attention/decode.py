@@ -2,9 +2,12 @@
 
 Serving-side companion of ``kernel.py``'s prefill engine, extending the
 same schedule vocabulary to the serving cache: instead of a rectangular
-``(B, KH, T, D)`` KV tensor, KV lives in a **page pool** ``(P, page, KH,
-D)`` addressed through a per-sequence **page table** — and the KV sweep
-walks only the pages a sequence actually occupies:
+``(B, KH, T, D)`` KV tensor, KV lives in a head-major **page pool** ``(P,
+KH, page, D)`` addressed through a per-sequence **page table** — and the
+KV sweep walks only the pages a sequence actually occupies.  Head-major
+makes each streamed KV block ``(page, D)`` in its last two dims, the
+(sublane, lane) tiling Mosaic requires; ``page`` must be a whole number
+of the pool dtype's sublane tiles (``serving/cache.CacheConfig``):
 
   * **Page-table index map** — the page table and the per-sequence
     context lengths ride in scalar-prefetch memory
@@ -175,6 +178,15 @@ def pages_touched(lengths, sched: FlashDecodeSchedule) -> int:
     return total
 
 
+def _head_scales(rows, h):
+    """Row ``h`` of a (KH, ps) block of scale rows as a (ps, 1) column,
+    one scale per page slot.  The row is picked by a masked sum (exact:
+    one value plus zeros), which needs no dynamic sublane slice."""
+    sel = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0) == h
+    row = jnp.sum(jnp.where(sel, rows, 0.0), axis=0, keepdims=True)
+    return row.reshape(rows.shape[1], 1)
+
+
 def _decode_kernel(pt_ref, len_ref, *rest, scale, window, softcap,
                    sched: FlashDecodeSchedule, kh, out_dtype, quant: bool,
                    has_new_lens: bool = False):
@@ -187,7 +199,7 @@ def _decode_kernel(pt_ref, len_ref, *rest, scale, window, softcap,
     q_ref, k_ref, v_ref, *rest = rest
     if quant:
         # the int8 layout streams two extra per-page operands: the
-        # (1, ps, 1) scale rows riding the same clamped page walk
+        # (1, KH, ps) scale rows riding the same clamped page walk
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
@@ -211,13 +223,13 @@ def _decode_kernel(pt_ref, len_ref, *rest, scale, window, softcap,
     def _compute():
         g = q_ref.shape[2]
         q = q_ref[0, 0].reshape(g * qc, q_ref.shape[-1])    # (g·qc, D)
-        k = k_ref[0, :, 0, :]               # (ps, D)
-        v = v_ref[0, :, 0, :]               # (ps, D)
+        k = k_ref[0, 0]                     # (ps, D)
+        v = v_ref[0, 0]                     # (ps, D)
         if quant:
             # fused dequant: values·scale in f32, right off the DMA — the
             # fp page never exists in HBM (only this VMEM tile does)
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
+            k = k.astype(jnp.float32) * _head_scales(ks_ref[0], n % kh)
+            v = v.astype(jnp.float32) * _head_scales(vs_ref[0], n % kh)
             q = q.astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -279,7 +291,8 @@ def paged_decode_kernel(q: jax.Array, k_pages: jax.Array,
 
       q          (B, H, q_len, D) — the step's new queries (1 for plain
                  decode, a whole prompt chunk for chunked prefill),
-      k_pages    (P, page, KH, D) — one layer's KV page pool (v_pages alike),
+      k_pages    (P, KH, page, D) — one layer's head-major KV page pool
+                 (v_pages alike),
       page_table (B, max_pages) int32 — physical page of logical page j,
       lengths    (B,) int32 — context length *including* the q_len new
                  tokens (their K/V must already be committed to the pages).
@@ -292,10 +305,11 @@ def paged_decode_kernel(q: jax.Array, k_pages: jax.Array,
     table and lengths travel via scalar prefetch so the KV index map
     resolves physical pages before each DMA.
 
-    ``k_scales``/``v_scales`` (P, page, KH) f32 select the quantized
+    ``k_scales``/``v_scales`` (P, KH, page) f32 select the quantized
     layout (``kv_quant="int8"``): the pools hold int8 rows and the scale
     pools stream alongside them through the *same* clamped page walk —
-    one (1, ps, 1) scale row per KV page block — with dequantization
+    one (1, KH, ps) block of scale rows per KV page block, of which the
+    kernel uses its own head's row — with dequantization
     (``values.astype(f32) * scale``) fused into the kernel body ahead of
     the QK/PV contractions.  The fp pages never materialize in HBM; the
     per-step KV bytes drop to ``1 + 4/D`` per element vs 2 for bf16.
@@ -310,13 +324,13 @@ def paged_decode_kernel(q: jax.Array, k_pages: jax.Array,
     the 2-operand launch bitwise identical to plain decode.
     """
     b, h, qs, d = q.shape
-    p_total, ps, kh, dk = k_pages.shape
+    p_total, kh, ps, dk = k_pages.shape
     assert d == dk and h % kh == 0, (q.shape, k_pages.shape)
     assert v_pages.shape == k_pages.shape
     quant = k_scales is not None
     assert quant == (v_scales is not None), "need both scale pools or neither"
     if quant:
-        assert k_scales.shape == (p_total, ps, kh), (
+        assert k_scales.shape == (p_total, kh, ps), (
             k_scales.shape, k_pages.shape)
         assert v_scales.shape == k_scales.shape
     max_pages = page_table.shape[1]
@@ -344,13 +358,14 @@ def paged_decode_kernel(q: jax.Array, k_pages: jax.Array,
         sb = n // kh
         j_lo, j_hi = bounds(len_ref[sb], i)
         # clamped sparse walk: trailing steps revisit j_hi (copy elided)
-        return (pt_ref[sb, jnp.minimum(j_lo + jj, j_hi)], 0, n % kh, 0)
+        return (pt_ref[sb, jnp.minimum(j_lo + jj, j_hi)], n % kh, 0, 0)
 
     def scale_index(n, i, jj, pt_ref, len_ref, *_refs):
-        # the scale row of exactly the page the KV walk fetches
+        # the scale rows of exactly the page the KV walk fetches (all KH
+        # of them: a (1, ps) block would break the (8, 128) tiling rule)
         sb = n // kh
         j_lo, j_hi = bounds(len_ref[sb], i)
-        return (pt_ref[sb, jnp.minimum(j_lo + jj, j_hi)], 0, n % kh)
+        return (pt_ref[sb, jnp.minimum(j_lo + jj, j_hi)], 0, 0)
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, softcap=softcap,
@@ -358,13 +373,13 @@ def paged_decode_kernel(q: jax.Array, k_pages: jax.Array,
         has_new_lens=new_lens is not None)
     in_specs = [
         pl.BlockSpec((1, 1, g, qc, d), q_index),
-        pl.BlockSpec((1, ps, 1, d), kv_index),
-        pl.BlockSpec((1, ps, 1, d), kv_index),
+        pl.BlockSpec((1, 1, ps, d), kv_index),
+        pl.BlockSpec((1, 1, ps, d), kv_index),
     ]
     operands = [qg, k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_index),
-                     pl.BlockSpec((1, ps, 1), scale_index)]
+        in_specs += [pl.BlockSpec((1, kh, ps), scale_index),
+                     pl.BlockSpec((1, kh, ps), scale_index)]
         operands += [k_scales, v_scales]
     scalars = [page_table.astype(jnp.int32), lengths.astype(jnp.int32)]
     if new_lens is not None:

@@ -72,13 +72,13 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
 
     q (B, q_len, H, D) — the step's new queries (q_len = 1 for plain
     decode, a whole prompt chunk for chunked paged prefill);
-    k_pages/v_pages (P, page, KH, D) one layer's page pool; page_table
-    (B, max_pages) int32; lengths (B,) int32 per-sequence context
+    k_pages/v_pages (P, KH, page, D) one layer's head-major page pool;
+    page_table (B, max_pages) int32; lengths (B,) int32 per-sequence context
     *including* the new tokens (their K/V already committed).  Returns
     (B, q_len, H, D).  ``q_chunk`` bounds the q rows resident per kernel
     block (multi-query-row steps; ignored by the dense oracle).
 
-    ``k_scales``/``v_scales`` (P, page, KH) f32 select the quantized
+    ``k_scales``/``v_scales`` (P, KH, page) f32 select the quantized
     ``kv_quant="int8"`` layout: int8 pools with per-row absmax scales,
     dequantized in-kernel (or inside the gather for the ref oracle) with
     the bitwise-identical ``values.astype(f32) * scale``.
@@ -96,7 +96,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     """
     mode = mode or kernel_mode()
     b, qs, h, d = q.shape
-    kh = k_pages.shape[2]
+    kh = k_pages.shape[1]
     assert h % kh == 0, (h, kh)
     scale = scale if scale is not None else d ** -0.5
 

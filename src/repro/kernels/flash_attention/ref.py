@@ -51,24 +51,26 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     """Materialize a paged pool back into a dense per-sequence cache.
 
-    pages (P, page, KH, D); page_table (B, max_pages) int32 →
+    pages (P, KH, page, D) head-major; page_table (B, max_pages) int32 →
     (B, max_pages·page, KH, D) — logical token order per sequence.
     """
     b, max_pages = page_table.shape
-    _, page, kh, d = pages.shape
-    return pages[page_table].reshape(b, max_pages * page, kh, d)
+    _, kh, page, d = pages.shape
+    return pages[page_table].swapaxes(2, 3).reshape(b, max_pages * page,
+                                                    kh, d)
 
 
 def paged_gather_scales(scales: jnp.ndarray,
                         page_table: jnp.ndarray) -> jnp.ndarray:
-    """Gather the per-(page-slot, kv-head) scale rows of a quantized pool.
+    """Gather the per-(kv-head, page-slot) scale rows of a quantized pool.
 
-    scales (P, page, KH) f32; page_table (B, max_pages) int32 →
+    scales (P, KH, page) f32; page_table (B, max_pages) int32 →
     (B, max_pages·page, KH) — token order matching ``paged_gather``.
     """
     b, max_pages = page_table.shape
-    _, page, kh = scales.shape
-    return scales[page_table].reshape(b, max_pages * page, kh)
+    _, kh, page = scales.shape
+    return scales[page_table].swapaxes(2, 3).reshape(b, max_pages * page,
+                                                     kh)
 
 
 def dequantize_gathered(values: jnp.ndarray,
@@ -88,7 +90,7 @@ def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                         new_lens: jnp.ndarray | None = None) -> jnp.ndarray:
     """Dense decode / chunked-prefill oracle over a paged cache.
 
-    q (B, H, q_len, D); pools (P, page, KH, D); lengths (B,) int32 is the
+    q (B, H, q_len, D); pools (P, KH, page, D); lengths (B,) int32 is the
     per-sequence context *including* the q_len new tokens → (B, H, q_len,
     D).  Row r of sequence b sits at position ``lengths[b] - q_len + r``;
     causality, the sliding window, and the uncommitted cache tail are all
@@ -98,7 +100,7 @@ def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     kernel launches (``q_chunk`` only changes the kernel's blocking,
     never the math).
 
-    ``k_scales``/``v_scales`` (P, page, KH) f32 make this the quantized
+    ``k_scales``/``v_scales`` (P, KH, page) f32 make this the quantized
     oracle: the int8 pools are gathered and dequantized row-wise
     (``values.astype(f32) * scale``) — the bitwise-specified dequant the
     kernel fuses into its page walk.
@@ -109,7 +111,7 @@ def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     rows are fully masked (0 output, matching the kernel).
     """
     b, h, qs, d = q.shape
-    kh = k_pages.shape[2]
+    kh = k_pages.shape[1]
     g = h // kh
     k = paged_gather(k_pages, page_table)           # (B, T, KH, D)
     v = paged_gather(v_pages, page_table)

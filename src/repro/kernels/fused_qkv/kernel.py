@@ -43,7 +43,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.tiling import ceil_div
+from repro.core.tiling import VMEM_LIMIT_BYTES, ceil_div
+
+# the scoped-VMEM limit the dispatcher's plans are budgeted against
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 _INT8_DOT = functools.partial(
     jax.lax.dot_general,
@@ -216,5 +219,6 @@ def fused_qkv_kernel(a_values, a_scale, wq, sq, wk, sk, wv, sv, *,
             jax.ShapeDtypeStruct((m, nkv), out_dtype),
         ),
         scratch_shapes=scratch_shapes,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(a_values, wq, wk, wv, a_scale, sq, sk, sv)

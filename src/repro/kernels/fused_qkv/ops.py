@@ -13,6 +13,8 @@ are handled natively (no host-side ``jnp.pad``), the same policy as
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -20,7 +22,7 @@ from repro.core.dispatch import Schedule, select_fused_plan
 from repro.core.quantization import QTensor
 from repro.kernels.fused_qkv import ref as _ref
 from repro.kernels.fused_qkv.kernel import fused_qkv_kernel
-from repro.kernels.tiled_matmul.ops import kernel_mode
+from repro.kernels.tiled_matmul.ops import kernel_mode, whole_on_each_device
 
 __all__ = ["fused_qkv"]
 
@@ -55,8 +57,8 @@ def fused_qkv(a: QTensor, wq: QTensor, wk: QTensor, wv: QTensor, *,
         block_n = block_n or plan.block_n
         if block_k is None and plan.schedule is Schedule.K_SPLIT:
             block_k = plan.block_k
-    return fused_qkv_kernel(a.values, a_scale, wq.values, sq,
-                            wk.values, sk, wv.values, sv,
-                            block_m=block_m, block_n=block_n,
-                            block_k=block_k,
-                            out_dtype=out_dtype, interpret=interpret)
+    kernel = functools.partial(fused_qkv_kernel, block_m=block_m,
+                               block_n=block_n, block_k=block_k,
+                               out_dtype=out_dtype, interpret=interpret)
+    return whole_on_each_device(kernel, a.values, a_scale, wq.values, sq,
+                                wk.values, sk, wv.values, sv)

@@ -15,6 +15,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.tiling import VMEM_LIMIT_BYTES, VMEM_PLAN_BUDGET
+
+def quant_act_block_m(k: int, itemsize: int) -> int:
+    """Rows per block: the most of 256, 128, … 8 whose footprint fits the
+    planner's VMEM budget (a whole row must be resident for its absmax,
+    so wide rows — a 11008-wide FFN activation — take fewer of them)."""
+    # per element: the input and the int8 output double-buffered, plus two
+    # f32 temporaries (the upcast row and x / scale)
+    row_bytes = k * (2 * itemsize + 2 * 1 + 2 * 4)
+    for bm in (256, 128, 64, 32, 16, 8):
+        if bm * row_bytes <= VMEM_PLAN_BUDGET:
+            return bm
+    return 8
 
 
 def _quant_act_kernel(x_ref, q_ref, s_ref, *, qmax):
@@ -43,5 +58,7 @@ def quant_act_kernel(x: jax.Array, *, block_m: int = 256, qmax: int = 127,
                    pl.BlockSpec((block_m, 1), lambda i: (i, 0))),
         out_shape=(jax.ShapeDtypeStruct((m, k), jnp.int8),
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x)

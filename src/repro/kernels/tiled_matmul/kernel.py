@@ -47,7 +47,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.tiling import ceil_div
+from repro.core.tiling import VMEM_LIMIT_BYTES, ceil_div
+
+# the scoped-VMEM limit the dispatcher's plans are budgeted against
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 _INT8_DOT = functools.partial(
     jax.lax.dot_general,
@@ -157,6 +160,7 @@ def tiled_matmul_kernel(a_values: jax.Array, a_scale: jax.Array,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
             out_shape=out_shape,
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
         )(*operands)
 
@@ -180,5 +184,6 @@ def tiled_matmul_kernel(a_values: jax.Array, a_scale: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)

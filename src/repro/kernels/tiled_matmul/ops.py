@@ -5,10 +5,10 @@ plans when the autotuner cache has one, analytic model otherwise), native
 partial tiles (paper §5: edge blocks masked in-kernel, NO host-side
 ``jnp.pad`` of operands on the Pallas path), and backend dispatch:
 
-  REPRO_KERNELS=ref                -> pure-jnp oracle (default on CPU: the
+  REPRO_KERNELS=ref                -> pure-jnp oracle (default off-TPU: the
                                       multi-pod dry-run compiles this path)
   REPRO_KERNELS=pallas_interpret   -> Pallas kernel, interpret mode (tests)
-  REPRO_KERNELS=pallas             -> compiled Pallas kernel (real TPU)
+  REPRO_KERNELS=pallas             -> compiled Pallas kernel (default on TPU)
 
 Both paths share the same dequant-epilogue math, so results are bitwise
 identical; tests assert this across shape/dtype sweeps.
@@ -19,11 +19,15 @@ the pad/slice copies cost versus the native path.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.dispatch import select_plan
 from repro.core.quantization import QTensor, quantize
@@ -31,14 +35,68 @@ from repro.core.tiling import MXU_DIM, round_up
 from repro.kernels.tiled_matmul import ref as _ref
 from repro.kernels.tiled_matmul.kernel import tiled_matmul_kernel
 
-__all__ = ["tiled_matmul", "quantized_matmul", "kernel_mode"]
+__all__ = ["tiled_matmul", "quantized_matmul", "kernel_mode",
+           "KERNEL_MODES", "replicated_operands", "whole_on_each_device"]
+
+KERNEL_MODES = ("ref", "pallas_interpret", "pallas")
 
 
 def kernel_mode() -> str:
+    """The kernel backend ops dispatch to when no ``mode`` is passed.
+
+    ``REPRO_KERNELS`` names it explicitly (the tests pick ``ref`` or
+    ``pallas_interpret``); unset, a TPU backend runs the compiled Pallas
+    kernels and any other backend the pure-jnp oracles.  An unknown name
+    raises, and an explicit non-``pallas`` mode on a TPU warns: it would
+    keep the compiled kernels off the chip.
+    """
     mode = os.environ.get("REPRO_KERNELS", "")
-    if mode:
-        return mode
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    on_tpu = jax.default_backend() == "tpu"
+    if not mode:
+        return "pallas" if on_tpu else "ref"
+    if mode not in KERNEL_MODES:
+        raise ValueError(f"REPRO_KERNELS must be one of {KERNEL_MODES}, "
+                         f"got {mode!r}")
+    if on_tpu and mode != "pallas":
+        warnings.warn(f"REPRO_KERNELS={mode} on a TPU backend: the compiled "
+                      "kernels will not run", stacklevel=2)
+    return mode
+
+
+# the mesh whose devices each hold every kernel operand whole (set only by
+# a caller that knows it: mesh-sharded serving replicates the params)
+_REPLICATED_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_kernels_replicated_mesh", default=None)
+
+
+@contextlib.contextmanager
+def replicated_operands(mesh):
+    """Declare that, inside, every Pallas kernel operand is replicated
+    over ``mesh``: params held whole on each device and activations
+    unsharded, as mesh-sharded serving holds them.  The kernel calls then
+    run whole on each device (``whole_on_each_device``).  A mesh that
+    shards params (training) must not be declared here."""
+    token = _REPLICATED_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _REPLICATED_MESH.reset(token)
+
+
+def whole_on_each_device(fn, *args):
+    """``fn(*args)`` — a Pallas call — on each device of the mesh that
+    ``replicated_operands`` declared.
+
+    GSPMD cannot partition a Mosaic kernel, so there the call runs under
+    ``shard_map`` with replicated operands and result: every device
+    computes the whole product, with no collective.  Outside such a
+    declaration it is a plain call.
+    """
+    mesh = _REPLICATED_MESH.get()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 def tiled_matmul(a: QTensor, b: QTensor, bias: jax.Array | None = None, *,
@@ -80,10 +138,11 @@ def tiled_matmul(a: QTensor, b: QTensor, bias: jax.Array | None = None, *,
             interpret=interpret)
 
     bi = bias.reshape(1, n).astype(jnp.float32) if bias is not None else None
-    return tiled_matmul_kernel(a.values, a_scale, b.values, b_scale, bi,
-                               block_m=block_m, block_n=block_n,
-                               block_k=block_k, out_dtype=out_dtype,
-                               interpret=interpret)
+    kernel = functools.partial(tiled_matmul_kernel, block_m=block_m,
+                               block_n=block_n, block_k=block_k,
+                               out_dtype=out_dtype, interpret=interpret)
+    return whole_on_each_device(kernel, a.values, a_scale, b.values,
+                                b_scale, bi)
 
 
 def _tiled_matmul_padded(av, a_scale, bv, b_scale, bias, *, block_m, block_n,

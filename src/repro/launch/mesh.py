@@ -4,9 +4,11 @@ FUNCTIONS, not module-level constants — importing this module never
 touches jax device state (the dry-run sets the host-device count before
 any jax initialization; see dryrun.py).
 
-``axis_types`` only exists on newer jax; ``_make_mesh`` falls back to the
-plain spelling so these helpers work on every supported version (the
-serving stack's shard_map collectives are indifferent to axis types).
+Every axis is ``Auto``: the model code annotates activations with
+``with_sharding_constraint`` and lets GSPMD propagate the rest.  Under
+``jax.make_mesh``'s default ``Explicit`` axes sharding is part of each
+array's type, and ops such as the embedding gather then refuse inputs
+whose output sharding is ambiguous.
 """
 from __future__ import annotations
 
@@ -14,11 +16,8 @@ import jax
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,6 +24,9 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs import get_config, get_smoke_config
     from repro.core.quantize_params import quantize_model_params
     from repro.models.transformer import init_model

@@ -185,9 +185,9 @@ def _attend_paged(params, q, k, v, cfg: ModelConfig, *, cache, cache_pos,
     """Paged-cache decode step: scatter new kv into pages, attend, project.
 
     q (B,S,H,hd), k/v (B,S,K,hd) — already rope'd; cache (k_pages,
-    v_pages) each (P, page, K, hd), or (k_pages, v_pages, k_scales,
-    v_scales) for the ``kv_quant="int8"`` layout (int8 pools + (P, page,
-    K) f32 scale rows); cache_pos (B,) per-sequence lengths before the
+    v_pages) each head-major (P, K, page, hd), or (k_pages, v_pages,
+    k_scales, v_scales) for the ``kv_quant="int8"`` layout (int8 pools +
+    (P, K, page) f32 scale rows); cache_pos (B,) per-sequence lengths before the
     write.  Quantized layouts quantize each new row per (token, kv-head)
     (``core.quantization.quantize_kv``) and scatter values and scales
     through the same page-table indices — the read side dequantizes
@@ -218,9 +218,10 @@ def _attend_paged(params, q, k, v, cfg: ModelConfig, *, cache, cache_pos,
     (``_paged_attend_split``).  GSPMD never sees the pool indexed by the
     table, so it can never decide to all-gather it.
     """
+    from repro.serving.cache import pool_page_size
     quant = len(cache) == 4
     ck, cv = cache[0], cache[1]
-    page = ck.shape[1]
+    page = pool_page_size(ck)
     tok_pos = cache_pos[:, None] + jnp.arange(s)[None, :]       # (B, S)
     if n_new is None:
         pidx = jnp.take_along_axis(page_table, tok_pos // page, axis=1)
@@ -274,14 +275,16 @@ def _attend_paged(params, q, k, v, cfg: ModelConfig, *, cache, cache_pos,
         cks, cvs = cache[2], cache[3]
         kq, k_sc = quantize_kv(k)             # (B,S,K,hd) int8, (B,S,K) f32
         vq, v_sc = quantize_kv(v)
-        ck = ck.at[pidx, slot].set(kq)
-        cv = cv.at[pidx, slot].set(vq)
-        cks = cks.at[pidx, slot].set(k_sc)
-        cvs = cvs.at[pidx, slot].set(v_sc)
+        # (pidx, slot) index the pool's page and in-page dims around its
+        # head dim: the (B, S) index dims lead, so (B,S,K,hd) rows fit
+        ck = ck.at[pidx, :, slot].set(kq)
+        cv = cv.at[pidx, :, slot].set(vq)
+        cks = cks.at[pidx, :, slot].set(k_sc)
+        cvs = cvs.at[pidx, :, slot].set(v_sc)
     else:
         cks = cvs = None
-        ck = ck.at[pidx, slot].set(k.astype(ck.dtype))
-        cv = cv.at[pidx, slot].set(v.astype(cv.dtype))
+        ck = ck.at[pidx, :, slot].set(k.astype(ck.dtype))
+        cv = cv.at[pidx, :, slot].set(v.astype(cv.dtype))
     lengths = cache_pos + (s if n_new is None else n_new)
 
     if _flash_engine_live(cfg):
@@ -330,14 +333,12 @@ def _attend_paged(params, q, k, v, cfg: ModelConfig, *, cache, cache_pos,
 # propagation choice can materialize (all-gather) it.
 # ---------------------------------------------------------------------------
 def _pool_specs(quant: bool, by: str) -> tuple:
-    """shard_map PartitionSpecs for (k_pages, v_pages[, k_scales,
-    v_scales]): KV-head dim over ``model`` (``by="heads"``) or page-pool
-    dim over ``model`` (``by="pages"``).  The same specs fit the step's
-    new-KV updates on the heads path — their head dim sits at the same
-    index as the pool's."""
+    """shard_map PartitionSpecs for one layer's head-major (k_pages,
+    v_pages[, k_scales, v_scales]): KV-head dim over ``model``
+    (``by="heads"``) or page-pool dim over ``model`` (``by="pages"``)."""
     from jax.sharding import PartitionSpec as P
     if by == "heads":
-        val, sc = P(None, None, "model", None), P(None, None, "model")
+        val, sc = P(None, "model", None, None), P(None, "model", None)
     else:
         val, sc = P("model", None, None, None), P("model", None, None)
     return (val, val, sc, sc) if quant else (val, val)
@@ -351,30 +352,30 @@ def _paged_scatter_sharded(mesh, by: str, pools: tuple, upds: tuple,
     are global page ids; each shard rebases them into its own slab and
     drops the writes it does not own (every page is owned by exactly one
     shard, so collectively the scatter lands exactly once)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     quant = len(pools) == 4
     pool_specs = _pool_specs(quant, by)
-    upd_specs = (pool_specs if by == "heads"
-                 else tuple(P(*([None] * len(sp)))
-                            for sp in pool_specs))
+    # updates are token-major (B, S, K[, hd]): heads split their dim 2
+    upd = (P(None, None, "model", None), P(None, None, "model")) \
+        if by == "heads" else (P(None, None, None, None), P(None, None, None))
+    upd_specs = (upd[0], upd[0], upd[1], upd[1]) if quant else upd[:1] * 2
 
     def scat(pidx, slot, *ops):
         ps, us = ops[:len(pools)], ops[len(pools):]
         if by == "heads":
-            return tuple(p.at[pidx, slot].set(u.astype(p.dtype))
+            return tuple(p.at[pidx, :, slot].set(u.astype(p.dtype))
                          for p, u in zip(ps, us))
         s_idx = jax.lax.axis_index("model")
         per = ps[0].shape[0]
         loc = pidx - s_idx * per
         tgt = jnp.where((loc >= 0) & (loc < per), loc, per)
-        return tuple(p.at[tgt, slot].set(u.astype(p.dtype), mode="drop")
+        return tuple(p.at[tgt, :, slot].set(u.astype(p.dtype), mode="drop")
                      for p, u in zip(ps, us))
 
     rep2 = P(None, None)
-    return shard_map(scat, mesh=mesh,
-                     in_specs=(rep2, rep2, *pool_specs, *upd_specs),
-                     out_specs=pool_specs, check_rep=False)(
+    return jax.shard_map(scat, mesh=mesh,
+                         in_specs=(rep2, rep2, *pool_specs, *upd_specs),
+                         out_specs=pool_specs, check_vma=False)(
         pidx, slot, *pools, *upds)
 
 
@@ -388,7 +389,6 @@ def _paged_attend_tp(q, tok_pos, page_table, lengths, pools,
     per-head math is identical to the unsharded path.  This is the
     ``(B·KVH, q_blocks, steps)`` kernel grid partitioned over its KVH
     factor."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     quant = len(pools) == 4
     pool_specs = _pool_specs(quant, "heads")
@@ -408,10 +408,10 @@ def _paged_attend_tp(q, tok_pos, page_table, lengths, pools,
                     window=window, softcap=cfg.attn_logit_softcap,
                     q_chunk=q_chunk, k_scales=cks_l, v_scales=cvs_l)
 
-            return shard_map(
+            return jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(qspec, P(None, None), P(None), *pool_specs),
-                out_specs=qspec, check_rep=False)(
+                out_specs=qspec, check_vma=False)(
                 q, page_table, lengths, *pools)
 
         return _run_windowed(_pdec, cfg, is_local)
@@ -419,7 +419,7 @@ def _paged_attend_tp(q, tok_pos, page_table, lengths, pools,
     def local(q_l, tokp, pt, loc_flag, *pl):
         from repro.kernels.flash_attention.ref import (
             dequantize_gathered, paged_gather, paged_gather_scales)
-        kh_l = pl[0].shape[2]
+        kh_l = pl[0].shape[1]
         kd = paged_gather(pl[0], pt)
         vd = paged_gather(pl[1], pt)
         if quant:
@@ -431,10 +431,10 @@ def _paged_attend_tp(q, tok_pos, page_table, lengths, pools,
                           window=cfg.sliding_window, is_local=loc_flag)
         return o.reshape(b, s, kh_l * g, hd)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(qspec, P(None, None), P(None, None), P(), *pool_specs),
-        out_specs=qspec, check_rep=False)(
+        out_specs=qspec, check_vma=False)(
         q, tok_pos, page_table, jnp.asarray(is_local, bool), *pools)
 
 
@@ -451,11 +451,12 @@ def _paged_attend_split(q, tok_pos, page_table, pools, cfg: ModelConfig,
     replicated, so only (B,H,S)-sized partials cross the wire — never
     KV).  Runs the gather-oracle math locally whatever the kernel mode —
     a partial-output kernel epilogue is the remaining TPU work."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.serving.cache import pool_page_size
     quant = len(pools) == 4
     pool_specs = _pool_specs(quant, "pages")
-    page = pools[0].shape[1]
+    page = pool_page_size(pools[0])
     kh, hd = cfg.n_kv_heads, cfg.head_dim
     g = cfg.n_heads // kh
 
@@ -494,10 +495,10 @@ def _paged_attend_split(q, tok_pos, page_table, pools, cfg: ModelConfig,
         return o.transpose(0, 3, 1, 2, 4).reshape(b, s, kh * g, hd)
 
     rep4 = P(None, None, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep4, P(None, None), P(None, None), P(), *pool_specs),
-        out_specs=rep4, check_rep=False)(
+        out_specs=rep4, check_vma=False)(
         q, tok_pos, page_table, jnp.asarray(is_local, bool), *pools)
 
 
@@ -522,7 +523,7 @@ def apply_attention(params: Params, x: jax.Array, cfg: ModelConfig, *,
         batches); new kv is written there and attention runs over the
         cache with per-sequence causal masking.
       * paged — ``page_table`` (B, max_pages) int32 is given and cache is
-        (k_pages, v_pages) each (P, page, K, hd) — or (k_pages, v_pages,
+        (k_pages, v_pages) each (P, K, page, hd) — or (k_pages, v_pages,
         k_scales, v_scales) for the int8-quantized page layout;
         ``cache_pos`` (B,) holds per-sequence lengths *before* this step.
         New kv is scattered into each sequence's pages and attention

@@ -13,8 +13,8 @@ One ``init_model`` / ``apply_model`` pair driven by ``ModelConfig``:
 
 Cache convention (decode) — see serving/cache.py + docs/DESIGN.md:
   dense:  {"k","v"}: (L, B, S_max, KVH, hd)     attention layers
-  paged:  {"k_pages","v_pages"}: (L, P, page, KVH, hd) page pools,
-          {"k_scales","v_scales"}: (L, P, page, KVH) f32 (kv_quant="int8"),
+  paged:  {"k_pages","v_pages"}: (L, P, KVH, page, hd) page pools,
+          {"k_scales","v_scales"}: (L, P, KVH, page) f32 (kv_quant="int8"),
           {"page_table"}: (B, max_pages) int32, {"seq_lens"}: (B,) int32
   {"shared_k","shared_v"}: (A, B, S_max, KVH, hd)   zamba2 shared block
   {"ssm_h"}: (L, B, H, P, N) f32; {"conv_x","conv_B","conv_C"} conv tails;

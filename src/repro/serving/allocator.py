@@ -228,7 +228,8 @@ def store_allocator(cache: dict, state: dict) -> dict:
 
 
 def _page_size(cache: dict) -> int:
-    return cache["k_pages"].shape[2]
+    from repro.serving.cache import pool_page_size
+    return pool_page_size(cache["k_pages"])
 
 
 def pool_occupancy(cache: dict) -> tuple[int, int]:

@@ -15,9 +15,11 @@ Two attention-cache layouts behind one ``init_cache`` API (see
 
 **paged** — fixed-size KV pages in a shared pool plus per-sequence page
 tables (attention families only; the SSM state is already O(1)):
-  k_pages/v_pages  (L, n_pages, page_size, KVH, hd)
-  k_scales/v_scales(L, n_pages, page_size, KVH) f32 — ``kv_quant="int8"``
-                   only: per-(page-slot, kv-head) symmetric absmax scales
+  k_pages/v_pages  (L, n_pages, KVH, page_size, hd) — head-major, so one
+                   page of one head is a (page_size, hd) tile, the block
+                   the paged flash kernel streams
+  k_scales/v_scales(L, n_pages, KVH, page_size) f32 — ``kv_quant="int8"``
+                   only: per-(kv-head, page-slot) symmetric absmax scales
                    for the int8 pools; they ride the *same* page table,
                    so everything that moves pages (CoW, prefix sharing)
                    moves their scale rows with them
@@ -31,10 +33,10 @@ Page-table invariants (``docs/DESIGN.md`` §2): entries are valid pool
 indices; distinct sequences never *write* the same physical page (a
 read-only shared prefix page may appear in several rows while its
 refcount is tracked by the allocator); token position ``p`` of sequence
-``b`` lives at ``(page_table[b, p // page_size], p % page_size)``; only
-the first ``seq_lens[b]`` positions hold committed data (later slots may
-hold prefill-padding garbage that decode masks until it overwrites
-them).
+``b``, head ``h`` lives at ``(page_table[b, p // page_size], h,
+p % page_size)``; only the first ``seq_lens[b]`` positions hold
+committed data (later slots may hold prefill-padding garbage that decode
+masks until it overwrites them).
 
 All construction knobs live in the frozen ``CacheConfig`` dataclass —
 layout/page/allocator choices plus the mesh and KV-sharding policy.  The
@@ -62,7 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
-from repro.core.tiling import ceil_div
+from repro.core.tiling import ceil_div, sublane_rows
 from repro.launch.sharding import DEFAULT_LOGICAL_RULES, tree_specs
 from repro.models.config import ModelConfig
 
@@ -151,6 +153,23 @@ class CacheConfig:
                 and self.resolved_kv_shard(n_kv_heads) == "pages"):
             return self.model_size()
         return 1
+
+    def check_page_tiling(self, kv_dtype, platform: str) -> None:
+        """Raise unless ``page_size`` is a whole number of the pool
+        dtype's sublane tiles (``tiling.sublane_rows``: 16 rows for bf16,
+        32 for the int8 pools) when the cache lives on a TPU.  A page is
+        the second-minor dim of every KV block the paged flash kernel
+        streams, and the compiler refuses a partial-tile block, so catch
+        it here rather than in the kernel.  Elsewhere (CPU, kernels
+        interpreted) any page size runs."""
+        if self.layout != "paged" or platform != "tpu":
+            return
+        dtype = jnp.dtype(jnp.int8 if self.kv_quant == "int8" else kv_dtype)
+        rows = sublane_rows(dtype.itemsize)
+        if self.page_size % rows:
+            raise ValueError(
+                f"page_size={self.page_size} is not a multiple of {rows}, "
+                f"the TPU sublane tile of the {dtype.name} KV pool")
 
     def logical_axes(self, cfg: ModelConfig) -> dict:
         return cache_logical_axes(
@@ -276,6 +295,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         # paged path addresses pages — seq_lens is the shared currency
         cache["seq_lens"] = jnp.zeros((batch,), jnp.int32)
     elif config.layout == "paged":
+        platform = (config.mesh.devices.flat[0].platform
+                    if config.mesh is not None else jax.default_backend())
+        config.check_page_tiling(dtype, platform)
         page_sz = config.page_size
         max_pages = ceil_div(max_len, page_sz)
         n_pages = (config.pool_pages if config.pool_pages is not None
@@ -286,14 +308,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         n_pages = ceil_div(n_pages, shards) * shards
         pool_dtype = jnp.int8 if config.kv_quant == "int8" else dtype
         cache["k_pages"] = jnp.zeros(
-            (cfg.n_layers, n_pages, page_sz, cfg.n_kv_heads, cfg.head_dim),
+            (cfg.n_layers, n_pages, cfg.n_kv_heads, page_sz, cfg.head_dim),
             pool_dtype)
         cache["v_pages"] = jnp.zeros_like(cache["k_pages"])
         if config.kv_quant == "int8":
             # zero scales dequantize the zero-initialised pool to exact
             # zeros — indistinguishable from the fp layout's fresh pages
             cache["k_scales"] = jnp.zeros(
-                (cfg.n_layers, n_pages, page_sz, cfg.n_kv_heads),
+                (cfg.n_layers, n_pages, cfg.n_kv_heads, page_sz),
                 jnp.float32)
             cache["v_scales"] = jnp.zeros_like(cache["k_scales"])
         if config.alloc == "dynamic":
@@ -323,6 +345,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return cache
 
 
+def pool_page_size(pool: jax.Array) -> int:
+    """Tokens per page of a head-major KV value pool, stacked
+    ``(L, P, KVH, page, hd)`` or one layer's ``(P, KVH, page, hd)``.
+    Every page-size read of the paged layout goes through here."""
+    return pool.shape[-2]
+
+
 def invalidate_token_rows(cache: dict, tok_pos: jnp.ndarray,
                           inv: jnp.ndarray) -> dict:
     """Zero the page-state rows holding the given token positions.
@@ -339,7 +368,7 @@ def invalidate_token_rows(cache: dict, tok_pos: jnp.ndarray,
     """
     from repro.serving.allocator import SCRATCH_PAGE
     pt = cache["page_table"]
-    page = cache["k_pages"].shape[2]
+    page = pool_page_size(cache["k_pages"])
     width = pt.shape[1]
     inv = inv & (tok_pos < width * page)
     pidx = jnp.take_along_axis(
@@ -349,7 +378,7 @@ def invalidate_token_rows(cache: dict, tok_pos: jnp.ndarray,
     out = dict(cache)
     for key in PAGE_STATE_KEYS:
         if key in out:
-            out[key] = out[key].at[:, pidx, slot].set(0)
+            out[key] = out[key].at[:, pidx, :, slot].set(0)
     return out
 
 
@@ -408,14 +437,14 @@ def cache_logical_axes(cfg: ModelConfig, kv_shard: str = "auto", *,
             axes["shared_v"] = kv
     elif layout == "paged":
         kv = _kv_axes(cfg, kv_shard, model_size)
-        # (L, P, page, KVH, hd): the per-sequence dims B/S are gone — the
+        # (L, P, KVH, page, hd): the per-sequence dims B/S are gone — the
         # pool's page dim takes the kv_seq split, heads keep theirs
         paged = (None, "kv_pages" if kv[2] == "kv_seq" else None,
-                 None, kv[3], None)
+                 kv[3], None, None)
         axes["k_pages"] = paged
         axes["v_pages"] = paged
         if kv_quant == "int8":
-            axes["k_scales"] = paged[:-1]          # (L, P, page, KVH)
+            axes["k_scales"] = paged[:-1]          # (L, P, KVH, page)
             axes["v_scales"] = paged[:-1]
         axes["page_table"] = ("batch", None)
         axes["seq_lens"] = ("batch",)

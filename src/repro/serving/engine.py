@@ -31,24 +31,30 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.tiled_matmul.ops import replicated_operands
 from repro.launch.sharding import activate_sharding
 from repro.models.config import ModelConfig
 from repro.models.transformer import apply_model
-from repro.serving.cache import SERVING_RULES, CacheConfig
+from repro.serving.cache import SERVING_RULES, CacheConfig, pool_page_size
 
 Params = dict
 
 
+@contextlib.contextmanager
 def _mesh_context(mesh):
     """Sharding context for serving model calls: under a mesh the
     attention path routes paged KV through the shard_map'd partitioned
     schedules (``models/attention.py``) and activation annotations bind;
     without one this is a no-op.  ``SERVING_RULES`` pins the pool's page
     dim to the ``model`` axis so decode collectives and the shard-local
-    allocator agree on the partitioning."""
+    allocator agree on the partitioning.  Serving holds params whole on
+    every device, so the Pallas GEMM / quantization kernels run whole on
+    each device (``replicated_operands``)."""
     if mesh is None:
-        return contextlib.nullcontext()
-    return activate_sharding(mesh, SERVING_RULES)
+        yield
+        return
+    with activate_sharding(mesh, SERVING_RULES), replicated_operands(mesh):
+        yield
 
 
 def prefill_step(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
@@ -137,7 +143,8 @@ def cache_capacity(cache: dict) -> int | None:
     """Token capacity of a decode cache, or None for pure-SSM state
     (O(1) in context length — no positional capacity to exceed)."""
     if "k_pages" in cache:
-        return cache["page_table"].shape[1] * cache["k_pages"].shape[2]
+        return (cache["page_table"].shape[1]
+                * pool_page_size(cache["k_pages"]))
     if "k" in cache:
         return cache["k"].shape[2]
     if "shared_k" in cache:
@@ -147,16 +154,21 @@ def cache_capacity(cache: dict) -> int | None:
     return None
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
+@functools.partial(jax.jit, static_argnames=("cfg", "mode", "mesh"))
 def _prefill_run(params, cache, prompts, prompt_lens, start_pos,
-                 cfg: ModelConfig, mesh=None):
-    """Jitted single-pass prefill body: one compile per (batch,
-    padded-width) shape.  ``start_pos`` rides in as a traced scalar so
-    prefix-shared admissions forking at *any* prefix length share the
-    same executable — the scheduler's bucketed padding bounds the shape
-    count, and admission ticks stop paying per-op eager dispatch for
-    the whole model.  The cache is not donated: scheduler admissions
-    prefill a slot *view* whose leaves the caller merges back."""
+                 cfg: ModelConfig, mode: str, mesh=None):
+    """Jitted prefill body for one pass or one chunk: one compile per
+    (batch, width) shape.  ``start_pos`` rides in as a traced scalar so
+    prefix-shared admissions forking at *any* prefix length — and every
+    chunk of a chunked prefill — share the same executable; the
+    scheduler's bucketed padding bounds the shape count, and admission
+    ticks stop paying per-op eager dispatch for the whole model.
+    ``mode`` (the live ``kernel_mode()``) only keys the jit cache, as in
+    ``_greedy_run``.  Logits come back at row ``prompt_lens - 1 -
+    start_pos`` (clamped into the block; the chunked caller keeps only
+    rows that fall inside).  The cache is not donated: scheduler
+    admissions prefill a slot *view* whose leaves the caller merges
+    back."""
     b, s_pad = prompts.shape
     pos0 = jnp.broadcast_to(start_pos, (b,)).astype(jnp.int32)
     nv = (jnp.clip(prompt_lens - start_pos, 0, s_pad)
@@ -164,8 +176,9 @@ def _prefill_run(params, cache, prompts, prompt_lens, start_pos,
     with _mesh_context(mesh):
         logits, cache, _ = apply_model(params, prompts, cfg, cache=cache,
                                        cache_pos=pos0, n_valid=nv)
-    next_logits = jnp.take_along_axis(
-        logits, (prompt_lens - 1 - start_pos)[:, None, None], axis=1)[:, 0]
+    rel = jnp.clip(prompt_lens - 1 - start_pos, 0, s_pad - 1)
+    next_logits = jnp.take_along_axis(logits, rel[:, None, None],
+                                      axis=1)[:, 0]
     return next_logits, cache
 
 
@@ -217,48 +230,40 @@ def prefill(params: Params, cache: dict, prompts: jax.Array,
                          f"capacity {capacity} tokens")
     prompt_lens = jnp.asarray(prompt_lens, jnp.int32)
     mesh = config.mesh if config is not None else None
+    from repro.kernels.tiled_matmul.ops import kernel_mode
     # SSM state is a recurrence, not an addressed buffer: padded tails
     # can't be masked after the fact, so each row's valid-token count
     # rides into the model and zeroes dt at padded steps (decay 1,
     # contribution 0 — right-padding invisible to the state)
     is_ssm = "ssm_h" in cache
-    if chunk is None or s_pad <= chunk:
+    # one pass, or chunk after chunk — each a cache-writing step
+    width = s_pad if chunk is None else chunk
+    next_logits = None
+    for c0 in range(0, s_pad, width):
+        cs = min(width, s_pad - c0)
+        pos = start_pos + c0
         if memory is None:
-            next_logits, cache = _prefill_run(
-                params, cache, prompts, prompt_lens,
-                jnp.asarray(start_pos, jnp.int32), cfg, mesh)
+            got, cache = _prefill_run(
+                params, cache, prompts[:, c0:c0 + cs], prompt_lens,
+                jnp.asarray(pos, jnp.int32), cfg, kernel_mode(), mesh)
         else:
-            pos0 = jnp.full((b,), start_pos, jnp.int32)
-            nv = (jnp.clip(prompt_lens - start_pos, 0, s_pad)
-                  if is_ssm else None)
-            with _mesh_context(mesh):
-                logits, cache, _ = apply_model(params, prompts, cfg,
-                                               cache=cache, cache_pos=pos0,
-                                               memory=memory, n_valid=nv)
-            next_logits = jnp.take_along_axis(
-                logits, (prompt_lens - 1 - start_pos)[:, None, None],
-                axis=1)[:, 0]
-    else:
-        next_logits = None
-        for c0 in range(0, s_pad, chunk):
-            cs = min(chunk, s_pad - c0)
-            pos0 = jnp.full((b,), start_pos + c0, jnp.int32)
-            nv = (jnp.clip(prompt_lens - (start_pos + c0), 0, cs)
-                  if is_ssm else None)
+            # encoder-decoder: the cross-attention memory step runs eagerly
+            nv = jnp.clip(prompt_lens - pos, 0, cs) if is_ssm else None
             with _mesh_context(mesh):
                 logits, cache, _ = apply_model(
                     params, prompts[:, c0:c0 + cs], cfg, cache=cache,
-                    cache_pos=pos0, memory=memory, n_valid=nv)
-            if next_logits is None:
-                next_logits = jnp.zeros((b, logits.shape[-1]), logits.dtype)
-            # each sequence's last real prompt token lives in exactly one
-            # chunk: harvest its logits as that chunk goes by
-            rel = prompt_lens - 1 - (start_pos + c0)
-            inside = (rel >= 0) & (rel < cs)
+                    cache_pos=jnp.full((b,), pos, jnp.int32), memory=memory,
+                    n_valid=nv)
             got = jnp.take_along_axis(
-                logits, jnp.clip(rel, 0, cs - 1)[:, None, None],
+                logits, jnp.clip(prompt_lens - 1 - pos, 0, cs - 1)[:, None,
+                                                                   None],
                 axis=1)[:, 0]
-            next_logits = jnp.where(inside[:, None], got, next_logits)
+        # each sequence's last real prompt token lives in exactly one
+        # chunk: harvest its logits as that chunk goes by
+        rel = prompt_lens - 1 - pos
+        inside = (rel >= 0) & (rel < cs)
+        next_logits = (got if next_logits is None
+                       else jnp.where(inside[:, None], got, next_logits))
     if "seq_lens" in cache:
         # padded tails were written but are NOT committed: visibility is
         # governed by seq_lens, and decode overwrites them slot by slot.
@@ -366,20 +371,21 @@ def spec_step(params: Params, draft_params: Params, cache: dict,
 
 
 @functools.partial(jax.jit, donate_argnums=(1,),
-                   static_argnames=("draft_cfg",))
+                   static_argnames=("draft_cfg", "mode"))
 def draft_prefill_row(draft_params, draft_cache, prompts, prompt_lens,
-                      start_pos, slot, draft_cfg: ModelConfig):
+                      start_pos, slot, draft_cfg: ModelConfig, mode: str):
     """Commit a prompt into row ``slot`` of the dense draft cache as one
     jitted call (slice → prefill → merge fused; the slot index rides in
     as a traced scalar so every admission shares one executable per
     padded width).  ``prompts`` is (1, S_pad); the draft's logits are
     discarded — the first spec tick re-drafts from the target's first
-    token.  The draft cache is donated: the scheduler owns it."""
+    token.  The draft cache is donated: the scheduler owns it.  ``mode``
+    (the live ``kernel_mode()``) keys the jit cache."""
     view = {key: jax.lax.dynamic_slice_in_dim(draft_cache[key], slot, 1,
                                               axis=1)
             for key in ("k", "v")}
     _, view = _prefill_run(draft_params, view, prompts, prompt_lens,
-                           start_pos, draft_cfg)
+                           start_pos, draft_cfg, mode)
     return {key: jax.lax.dynamic_update_slice_in_dim(
                 draft_cache[key], view[key], slot, axis=1)
             for key in ("k", "v")}

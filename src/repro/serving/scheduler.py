@@ -390,14 +390,17 @@ class Scheduler:
                         f"pool of {self.pool_occupancy()[1]} offers")
                 return                       # pool full: wait for retires
             self.queue.popleft()
-            first = self._prefill_slot(b, req.prompt, start=shared)
+            first = int(jnp.argmax(
+                self._prefill_slot(b, req.prompt, start=shared)))
             self.slots[b] = _Slot(req, [first], first,
                                   admitted=self._ticks,
                                   token_ticks=[self._ticks])
 
-    def _prefill_slot(self, b: int, prompt: np.ndarray, start: int) -> int:
+    def _prefill_slot(self, b: int, prompt: np.ndarray,
+                      start: int) -> jax.Array:
         """Commit ``prompt[start:]`` into row ``b``'s pages (positions
-        ``start..``) and return the first greedy token."""
+        ``start..``) and return the logits (V,) after its last token."""
+        from repro.kernels.tiled_matmul.ops import kernel_mode
         suffix = prompt[start:]
         pad = -suffix.size % self.bucket
         padded = np.pad(suffix, (0, pad))
@@ -417,9 +420,9 @@ class Scheduler:
                 jnp.asarray(padded[None]),
                 jnp.asarray([prompt.size], jnp.int32),
                 jnp.asarray(start, jnp.int32), jnp.asarray(b, jnp.int32),
-                self.spec.draft_cfg)
+                self.spec.draft_cfg, kernel_mode())
         self._pin_shardings()
-        return int(jnp.argmax(nl[0]))
+        return nl[0]
 
     def _pin_shardings(self):
         """Re-place cache leaves on their expected shardings (mesh only).

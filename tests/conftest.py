@@ -7,10 +7,23 @@ os.environ.setdefault("REPRO_KERNELS", "ref")
 # NOTE: no xla_force_host_platform_device_count here — smoke tests and
 # benches must see 1 device (multi-device sharding tests use subprocesses).
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO_ROOT, "src"))
 
 
 import pytest
+
+
+@pytest.fixture
+def cpu_subprocess():
+    """``subprocess.run`` keywords for a child Python that must stay on the
+    CPU: this environment plus ``JAX_PLATFORMS=cpu`` and ``src`` on the
+    path, run from the repository root."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(REPO_ROOT, "src"), env.get("PYTHONPATH"))
+        if p)
+    return {"env": env, "cwd": REPO_ROOT}
 
 
 @pytest.fixture

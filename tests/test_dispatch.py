@@ -16,7 +16,7 @@ import pytest
 from _hypothesis_compat import given, st
 from repro.core import dispatch
 from repro.core.quantization import QTensor, quantize
-from repro.core.tiling import VMEM_BYTES, choose_plan
+from repro.core.tiling import VMEM_PLAN_BUDGET, choose_plan
 from repro.kernels.fused_qkv.ops import fused_qkv
 from repro.kernels.tiled_matmul.ops import tiled_matmul
 
@@ -195,14 +195,14 @@ def test_handshipped_entry_without_block_k_is_panel(tune_cache, monkeypatch):
 
 
 def test_oversized_cache_entry_rejected(tune_cache, monkeypatch):
-    """Entries beyond the half-VMEM planning budget fall back to analytic."""
+    """Entries beyond the VMEM planning budget fall back to analytic."""
     m, k, n = 512, 65536, 512
     monkeypatch.setenv(dispatch.TUNE_ENV, "cached")
     tune_cache.write_text(json.dumps({
         f"{m}x{k}x{n}:float32": {"block_m": 512, "block_n": 512,
                                  "block_k": k}}))
     plan = dispatch.select_plan(m, k, n, out_dtype=jnp.float32)
-    assert plan.fits_vmem(VMEM_BYTES // 2)
+    assert plan.fits_vmem(VMEM_PLAN_BUDGET)
     assert (plan.block_m, plan.block_n, plan.block_k) != (512, 512, k)
 
 
@@ -210,14 +210,55 @@ def test_fused_blocks_revalidated_for_fused_footprint(tune_cache,
                                                       monkeypatch):
     """A K-split single-GEMM plan cannot leak into the panel-only fused
     kernel: select_fused_blocks must return shapes whose *fused* footprint
-    (A panel + three double-buffered weight streams) fits the budget."""
-    m, k, n = 512, 28672, 4096
+    (A panel + three double-buffered weight streams) fits the budget.
+    (K=8192: past ~20k no fused panel fits the compiled VMEM limit.)"""
+    m, k, n = 512, 8192, 4096
     monkeypatch.setenv(dispatch.TUNE_ENV, "cached")
     tune_cache.write_text(json.dumps({
         f"{m}x{k}x{n}:bfloat16": {"block_m": 512, "block_n": 512,
                                   "block_k": 256}}))
     bm, bn = dispatch.select_fused_blocks(m, k, n, out_dtype=jnp.bfloat16)
-    assert dispatch._fused_qkv_footprint(bm, bn, k, 2) <= VMEM_BYTES // 2
+    assert dispatch._fused_qkv_footprint(bm, bn, k, 2) <= VMEM_PLAN_BUDGET
+
+
+def test_user_cache_read_only_when_named(monkeypatch):
+    """Without REPRO_TUNE_CACHE plan selection reads only the shipped
+    seed table (no file outside the checkout), and ``full`` — which must
+    write its measurements somewhere — refuses to start."""
+    monkeypatch.delenv(dispatch.CACHE_ENV, raising=False)
+    monkeypatch.delenv(dispatch.SEED_ENV, raising=False)
+    dispatch.reset_cache_state()
+    try:
+        assert dispatch.cache_path() is None
+        assert dispatch.load_cache() == dispatch._read_table(
+            dispatch.seed_table_path())
+        monkeypatch.setenv(dispatch.TUNE_ENV, "full")
+        with pytest.raises(ValueError, match=dispatch.CACHE_ENV):
+            dispatch.tune_mode()
+    finally:
+        dispatch.reset_cache_state()
+
+
+def test_measurement_failure_raises_on_tpu(tune_cache, monkeypatch):
+    """REPRO_TUNE=full: on the chip a kernel that fails to compile or run
+    during measurement raises; elsewhere it warns and keeps the analytic
+    plan."""
+    def refuse(*args, **kw):
+        raise RuntimeError("kernel refused")
+
+    monkeypatch.setenv(dispatch.TUNE_ENV, "full")
+    monkeypatch.setattr(dispatch, "tune", refuse)
+    monkeypatch.setattr(dispatch, "tune_fused", refuse)
+    monkeypatch.setattr(dispatch, "_measurement_backend", lambda _: "tpu")
+    with pytest.raises(RuntimeError, match="refused"):
+        dispatch.select_plan(100, 300, 513)
+    with pytest.raises(RuntimeError, match="refused"):
+        dispatch.select_fused_plan(8, 256, 256, 128)
+    monkeypatch.setattr(dispatch, "_measurement_backend",
+                        lambda _: "interpret")
+    with pytest.warns(UserWarning, match="analytic"):
+        assert dispatch.select_plan(100, 300, 513) == choose_plan(100, 300,
+                                                                  513)
 
 
 def test_invalid_tune_mode_rejected(monkeypatch):
@@ -234,7 +275,7 @@ def test_candidates_fit_vmem_and_cover(m, k, n):
     plans = dispatch.candidate_plans(m, k, n)
     assert plans, (m, k, n)
     for plan in plans:
-        assert plan.fits_vmem(VMEM_BYTES // 2), (plan, plan.vmem_footprint)
+        assert plan.fits_vmem(VMEM_PLAN_BUDGET), (plan, plan.vmem_footprint)
         # ceil-grid coverage of the logical problem
         grid = dispatch.grid_shape(m, n, plan)
         assert grid[0] * plan.block_m >= m

@@ -16,7 +16,7 @@ import pytest
 from repro.core import dispatch
 from repro.core.dispatch import FusedPlan, Schedule
 from repro.core.quantization import quantize
-from repro.core.tiling import VMEM_BYTES
+from repro.core.tiling import VMEM_PLAN_BUDGET
 from repro.kernels.fused_qkv.ops import fused_qkv
 
 RNG = np.random.default_rng(11)
@@ -114,7 +114,7 @@ def test_fused_tune_cache_roundtrip(tune_cache, monkeypatch):
     analytic = dispatch.select_fused_plan(m, kd, nq, nkv,
                                           out_dtype=jnp.float32)
     assert analytic == dispatch._analytic_fused_plan(
-        m, kd, nq, nkv, out_bytes=4, vmem_budget=VMEM_BYTES // 2)
+        m, kd, nq, nkv, out_bytes=4, vmem_budget=VMEM_PLAN_BUDGET)
 
 
 def test_fused_key_distinguishes_nq_nkv_split(tune_cache, monkeypatch):
@@ -131,7 +131,7 @@ def test_fused_key_distinguishes_nq_nkv_split(tune_cache, monkeypatch):
     other = dispatch.select_fused_plan(m, kd, 192, 96,
                                        out_dtype=jnp.float32)
     assert other == dispatch._analytic_fused_plan(
-        m, kd, 192, 96, out_bytes=4, vmem_budget=VMEM_BYTES // 2)
+        m, kd, 192, 96, out_bytes=4, vmem_budget=VMEM_PLAN_BUDGET)
 
 
 def test_legacy_single_gemm_key_fallback_panel(tune_cache, monkeypatch):
@@ -158,7 +158,7 @@ def test_legacy_ksplit_single_key_maps_to_fused_ksplit(tune_cache,
     plan = dispatch.select_fused_plan(m, kd, n, n, out_dtype=jnp.bfloat16)
     assert plan.schedule is Schedule.K_SPLIT
     assert (plan.block_m, plan.block_n, plan.block_k) == (256, 256, 2048)
-    assert plan.fits_vmem(VMEM_BYTES // 2, out_bytes=2)
+    assert plan.fits_vmem(VMEM_PLAN_BUDGET, out_bytes=2)
 
 
 def test_fused_entry_without_schedule_inferred_from_block_k(tune_cache,
@@ -182,7 +182,7 @@ def test_oversized_fused_entry_rejected(tune_cache, monkeypatch):
                                          "block_k": kd,
                                          "schedule": "panel"}}))
     plan = dispatch.select_fused_plan(m, kd, nq, nq, out_dtype=jnp.bfloat16)
-    assert plan.fits_vmem(VMEM_BYTES // 2, out_bytes=2)
+    assert plan.fits_vmem(VMEM_PLAN_BUDGET, out_bytes=2)
     assert (plan.block_m, plan.block_n, plan.block_k) != (512, 512, kd)
 
 
@@ -192,9 +192,9 @@ def test_analytic_huge_k_picks_ksplit():
     under-filled minimum panel)."""
     plan = dispatch._analytic_fused_plan(512, 262144, 4096, 4096,
                                          out_bytes=2,
-                                         vmem_budget=VMEM_BYTES // 2)
+                                         vmem_budget=VMEM_PLAN_BUDGET)
     assert plan.schedule is Schedule.K_SPLIT
-    assert plan.fits_vmem(VMEM_BYTES // 2, out_bytes=2)
+    assert plan.fits_vmem(VMEM_PLAN_BUDGET, out_bytes=2)
 
 
 def test_fused_candidates_cover_both_schedules():
@@ -205,7 +205,7 @@ def test_fused_candidates_cover_both_schedules():
     scheds = {p.schedule for p in plans}
     assert scheds == {Schedule.PANEL, Schedule.K_SPLIT}
     for p in plans:
-        assert p.footprint(2) <= VMEM_BYTES // 2
+        assert p.footprint(2) <= VMEM_PLAN_BUDGET
 
 
 # ---------------------------------------------------------------------------

@@ -133,3 +133,26 @@ def test_fused_qkv_shares_activation_quant():
     q, k, v = apply_fused_qkv(*ps, x, mode="w8a8", out_dtype=jnp.float32)
     q2 = apply_linear(ps[0], x, mode="w8a8", out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(q), np.asarray(q2), atol=1e-6)
+
+
+def test_kernel_mode_selection(monkeypatch):
+    """REPRO_KERNELS names the mode (unknown names raise); unset, a TPU
+    runs the compiled kernels and anything else the jnp oracles; an
+    explicit non-compiled mode on a TPU warns."""
+    import warnings
+
+    from repro.kernels.tiled_matmul.ops import kernel_mode
+    monkeypatch.setenv("REPRO_KERNELS", "pallas_interpret")
+    assert kernel_mode() == "pallas_interpret"
+    monkeypatch.setenv("REPRO_KERNELS", "mosaic")
+    with pytest.raises(ValueError, match="REPRO_KERNELS"):
+        kernel_mode()
+    monkeypatch.delenv("REPRO_KERNELS")
+    assert kernel_mode() == "ref"                   # this CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert kernel_mode() == "pallas"
+    monkeypatch.setenv("REPRO_KERNELS", "pallas_interpret")
+    with pytest.warns(UserWarning, match="TPU"):
+        assert kernel_mode() == "pallas_interpret"

@@ -47,17 +47,19 @@ KEY = jax.random.PRNGKey(0)
 # ---------------------------------------------------------------------------
 def _quant_pools(hist, page, table):
     """Quantize a dense (B, T, KH, D) history row-wise and scatter it
-    into (P, page, KH, D) int8 pools + (P, page, KH) f32 scales."""
+    into head-major (P, KH, page, D) int8 pools + (P, KH, page) f32
+    scales."""
     b, t, kh, d = hist.shape
     mp = t // page
     q, s = quantize_kv(jnp.asarray(hist))
     q, s = np.asarray(q), np.asarray(s)
-    pool = np.zeros((b * mp, page, kh, d), np.int8)
-    scales = np.zeros((b * mp, page, kh), np.float32)
+    pool = np.zeros((b * mp, kh, page, d), np.int8)
+    scales = np.zeros((b * mp, kh, page), np.float32)
     for bb in range(b):
         for j in range(mp):
-            pool[int(table[bb, j])] = q[bb, j * page:(j + 1) * page]
-            scales[int(table[bb, j])] = s[bb, j * page:(j + 1) * page]
+            rows = slice(j * page, (j + 1) * page)
+            pool[int(table[bb, j])] = q[bb, rows].swapaxes(0, 1)
+            scales[int(table[bb, j])] = s[bb, rows].T
     return jnp.asarray(pool), jnp.asarray(scales)
 
 
@@ -135,8 +137,8 @@ def test_init_cache_int8_shapes_and_errors():
     mp = 3
     assert cache["k_pages"].dtype == jnp.int8
     assert cache["v_pages"].dtype == jnp.int8
-    assert cache["k_scales"].shape == (cfg.n_layers, 2 * mp, 16,
-                                       cfg.n_kv_heads)
+    assert cache["k_scales"].shape == (cfg.n_layers, 2 * mp,
+                                       cfg.n_kv_heads, 16)
     assert cache["k_scales"].dtype == jnp.float32
     assert cache["v_scales"].shape == cache["k_scales"].shape
     with pytest.raises(ValueError, match="layout='paged'"):

@@ -41,15 +41,17 @@ KEY = jax.random.PRNGKey(0)
 # helpers
 # ---------------------------------------------------------------------------
 def _pools_from_history(hist_k, hist_v, page, table):
-    """Scatter a dense (B, T, KH, D) history into (P, page, KH, D) pools."""
+    """Scatter a dense (B, T, KH, D) history into head-major
+    (P, KH, page, D) pools."""
     b, t, kh, d = hist_k.shape
     mp = t // page
-    kp = np.zeros((b * mp, page, kh, d), hist_k.dtype)
+    kp = np.zeros((b * mp, kh, page, d), hist_k.dtype)
     vp = np.zeros_like(kp)
     for bb in range(b):
         for j in range(mp):
-            kp[int(table[bb, j])] = hist_k[bb, j * page:(j + 1) * page]
-            vp[int(table[bb, j])] = hist_v[bb, j * page:(j + 1) * page]
+            rows = slice(j * page, (j + 1) * page)
+            kp[int(table[bb, j])] = hist_k[bb, rows].swapaxes(0, 1)
+            vp[int(table[bb, j])] = hist_v[bb, rows].swapaxes(0, 1)
     return jnp.asarray(kp), jnp.asarray(vp)
 
 
@@ -186,8 +188,8 @@ def test_init_cache_paged_shapes():
     cache = init_cache(cfg, 2, max_len=40,
                        config=CacheConfig(layout="paged", page_size=16))
     mp = 3                                    # ceil(40/16)
-    assert cache["k_pages"].shape == (cfg.n_layers, 2 * mp, 16,
-                                      cfg.n_kv_heads, cfg.head_dim)
+    assert cache["k_pages"].shape == (cfg.n_layers, 2 * mp, cfg.n_kv_heads,
+                                      16, cfg.head_dim)
     assert cache["v_pages"].shape == cache["k_pages"].shape
     assert cache["page_table"].shape == (2, mp)
     assert cache["page_table"].dtype == jnp.int32
@@ -195,6 +197,27 @@ def test_init_cache_paged_shapes():
     with pytest.raises(ValueError):
         init_cache(get_smoke_config("mamba2_370m"), 2, max_len=40,
                    config=CacheConfig(layout="paged"))
+
+
+def test_cache_config_page_tiling(monkeypatch):
+    """On a TPU a page must be whole sublane tiles of the pool dtype — 16
+    rows for bf16, 32 for the int8 pools — and init_cache refuses any
+    other before a kernel sees it; off the TPU any page size runs."""
+    def cc(page, quant="none"):
+        return CacheConfig(layout="paged", page_size=page, kv_quant=quant)
+
+    cc(16).check_page_tiling(jnp.bfloat16, "tpu")
+    cc(64, "int8").check_page_tiling(jnp.bfloat16, "tpu")
+    cc(4).check_page_tiling(jnp.float32, "cpu")
+    for page, quant, dtype, rows in ((8, "none", jnp.bfloat16, 16),
+                                     (16, "int8", jnp.bfloat16, 32),
+                                     (4, "none", jnp.float32, 8)):
+        with pytest.raises(ValueError, match=f"multiple of {rows}"):
+            cc(page, quant).check_page_tiling(dtype, "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="multiple of 16"):
+        init_cache(get_smoke_config("qwen2_5_3b"), 2, max_len=32,
+                   dtype=jnp.bfloat16, config=cc(8))
 
 
 def test_cache_logical_axes_paged():
@@ -208,7 +231,7 @@ def test_cache_logical_axes_paged():
     axes_seq = cache_logical_axes(cfg, kv_shard="seq", layout="paged")
     assert axes_seq["k_pages"][1] == "kv_pages"
     axes_h = cache_logical_axes(cfg, kv_shard="heads", layout="paged")
-    assert axes_h["k_pages"][3] == "kv_heads"
+    assert axes_h["k_pages"][2] == "kv_heads"
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,7 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core.quantization import (Calibrator, dequantize, fake_quantize,
                                      qmax_for_bits, quantize)
-from repro.core.tiling import MXU_DIM, TilePlan, choose_plan, VMEM_BYTES
+from repro.core.tiling import MXU_DIM, TilePlan, choose_plan, VMEM_PLAN_BUDGET
 
 settings.register_profile("ci", max_examples=30, deadline=None)
 settings.load_profile("ci")
@@ -89,12 +89,39 @@ def test_calibrator_fixed_scale():
 @given(st.integers(1, 4096), st.integers(1, 8192), st.integers(1, 8192))
 def test_choose_plan_fits_and_covers(m, k, n):
     plan = choose_plan(m, k, n)
-    assert plan.fits_vmem(VMEM_BYTES // 2)
+    assert plan.fits_vmem(VMEM_PLAN_BUDGET)
     # full coverage: blocks tile the (padded) problem
     assert plan.block_m % MXU_DIM == 0 or plan.block_m >= m
     assert -(-m // plan.block_m) * plan.block_m >= m
     assert -(-n // plan.block_n) * plan.block_n >= n
     assert plan.k_steps * plan.block_k >= k
+
+
+def test_vmem_footprint_double_buffers_every_block():
+    """The footprint is what Pallas allocates: every input and output
+    block double-buffered (the j-invariant A panel too), each padded to
+    the VMEM tiling, plus the int32 product and its f32 dequant — and the
+    K-split accumulator.  qwen2.5's down projection as one 256-row chunk
+    then plans inside the limit the kernel is compiled with."""
+    from repro.core.tiling import VMEM_LIMIT_BYTES, vmem_tile_bytes
+    m, k, n = 256, 11008, 2048
+    out = vmem_tile_bytes(256, 512, 2)
+    scales = vmem_tile_bytes(256, 1, 4) + 2 * vmem_tile_bytes(1, 512, 4)
+    acc = vmem_tile_bytes(256, 512, 4)
+    panel = TilePlan(m, k, n, block_m=256, block_n=512, block_k=k)
+    a_panel = vmem_tile_bytes(256, k, 1)
+    b_panel = vmem_tile_bytes(k, 512, 1)
+    assert panel.vmem_footprint >= 2 * (a_panel + b_panel)
+    assert panel.vmem_footprint == (2 * (a_panel + b_panel + out + scales)
+                                    + 2 * acc)
+    split = TilePlan(m, k, n, block_m=256, block_n=512, block_k=2048)
+    assert split.vmem_footprint == 2 * (
+        vmem_tile_bytes(256, 2048, 1) + vmem_tile_bytes(2048, 512, 1)
+        + out + scales) + 3 * acc
+    # an 8-row int8 block still occupies a whole 32-row sublane tile
+    assert vmem_tile_bytes(8, 128, 1) == vmem_tile_bytes(32, 128, 1)
+    plan = choose_plan(m, k, n)
+    assert plan.vmem_footprint <= VMEM_PLAN_BUDGET < VMEM_LIMIT_BYTES
 
 
 @given(st.integers(64, 1024), st.integers(64, 4096), st.integers(64, 4096))

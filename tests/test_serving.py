@@ -182,11 +182,11 @@ def test_refcount_shared_page_survives_parent_free():
 # opacity: dynamic tables and shared pages are invisible to the read path
 # ---------------------------------------------------------------------------
 def _scatter_history(pools_shape, table_row, hist, page):
-    """Scatter a (T, KH, D) history into a (P, page, KH, D) pool along
-    ``table_row``."""
+    """Scatter a (T, KH, D) history into a head-major (P, KH, page, D)
+    pool along ``table_row``."""
     kp = np.zeros(pools_shape, hist.dtype)
     for j in range(hist.shape[0] // page):
-        kp[int(table_row[j])] = hist[j * page:(j + 1) * page]
+        kp[int(table_row[j])] = hist[j * page:(j + 1) * page].swapaxes(0, 1)
     return kp
 
 
@@ -207,7 +207,7 @@ def test_dynamic_table_bitwise_matches_contiguous():
     hist_v = RNG.normal(size=(t, kh, d)).astype(np.float32)
     q = jnp.asarray(RNG.normal(size=(1, 1, 4, d)).astype(np.float32))
     lens = jnp.asarray([50], jnp.int32)
-    pool_shape = (int(cache["k_pages"].shape[1]), page, kh, d)
+    pool_shape = (int(cache["k_pages"].shape[1]), kh, page, d)
 
     outs = []
     for table in (row[None], np.asarray(default_page_table(1, t // page))):
@@ -337,12 +337,13 @@ def test_multi_q_block_kernel_parity(qs, q_chunk, window, lens):
     table = default_page_table(b, t // page, "striped")
     hk = RNG.normal(size=(b, t, kh, d)).astype(np.float32)
     hv = RNG.normal(size=(b, t, kh, d)).astype(np.float32)
-    pool = np.zeros((b * t // page, page, kh, d), np.float32)
+    pool = np.zeros((b * t // page, kh, page, d), np.float32)  # head-major
     kp, vp = pool.copy(), pool.copy()
     for bb in range(b):
         for j in range(t // page):
-            kp[int(table[bb, j])] = hk[bb, j * page:(j + 1) * page]
-            vp[int(table[bb, j])] = hv[bb, j * page:(j + 1) * page]
+            rows = slice(j * page, (j + 1) * page)
+            kp[int(table[bb, j])] = hk[bb, rows].swapaxes(0, 1)
+            vp[int(table[bb, j])] = hv[bb, rows].swapaxes(0, 1)
     q = jnp.asarray(RNG.normal(size=(b, qs, h, d)).astype(np.float32))
     args = (q, jnp.asarray(kp), jnp.asarray(vp), table,
             jnp.asarray(lens, jnp.int32))

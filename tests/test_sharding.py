@@ -92,7 +92,7 @@ def test_fsdp_rules_keep_tables_unsharded_on_data():
 
 
 @pytest.mark.slow
-def test_multi_device_end_to_end():
+def test_multi_device_end_to_end(cpu_subprocess):
     """8 fake devices: params sharded, train step runs, loss finite, and
     the result matches single-device execution."""
     prog = textwrap.dedent("""
@@ -109,10 +109,9 @@ def test_multi_device_end_to_end():
         from repro.launch.sharding import (activate_sharding, param_specs,
                                            make_param_rules,
                                            make_activation_rules)
+        from repro.launch.mesh import make_host_mesh
         cfg = get_smoke_config("qwen2_5_3b").replace(dtype="float32")
-        # axis_types= (and jax.sharding.AxisType) only exist on jax >= 0.5;
-        # the default (auto) axis semantics are what we want on 0.4.x too.
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(data=2, model=4)
         params = init_model(jax.random.PRNGKey(0), cfg)
         opt = AdamW(learning_rate=1e-3)
         state = TrainState.create(params, opt)
@@ -150,8 +149,5 @@ def test_multi_device_end_to_end():
         print("MULTIDEVICE_OK")
     """)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=600,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root"},
-                         cwd="/root/repo")
+                         text=True, timeout=600, **cpu_subprocess)
     assert "MULTIDEVICE_OK" in res.stdout, res.stderr[-3000:]

@@ -195,7 +195,7 @@ def test_single_shard_reduces_to_flat_allocator():
 # sharded decode parity (subprocess: fake devices before jax import)
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
-def test_sharded_serving_parity_and_partitioning():
+def test_sharded_serving_parity_and_partitioning(cpu_subprocess):
     """Mesh sizes 1 / 2 / 4 over the same mixed-arrival trace: identical
     greedy tokens per request; pool leaves actually partitioned; no
     pool-sized all-gather in the compiled decode."""
@@ -253,7 +253,7 @@ def test_sharded_serving_parity_and_partitioning():
                 assert not sh.is_fully_replicated, (msize, key)
                 assert sh.is_equivalent_to(want[key],
                                            sched.cache[key].ndim), key
-            dim = 3 if policy == "heads" else 1
+            dim = 2 if policy == "heads" else 1     # (L, P, KVH, page, hd)
             assert want["k_pages"].spec[dim] == "model", want["k_pages"]
             if policy == "pages":
                 assert sched.cache["alloc_free"].shape[0] == msize
@@ -282,16 +282,13 @@ def test_sharded_serving_parity_and_partitioning():
         print("SHARDED_SERVING_OK")
     """)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=600,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root"},
-                         cwd="/root/repo")
+                         text=True, timeout=600, **cpu_subprocess)
     assert "SHARDED_SERVING_OK" in res.stdout, \
         res.stdout[-2000:] + res.stderr[-3000:]
 
 
 @pytest.mark.slow
-def test_sharded_prefix_sharing_and_int8():
+def test_sharded_prefix_sharing_and_int8(cpu_subprocess):
     """The sharded pool composes with the rest of the serving stack:
     prefix-shared admissions and int8 page pools both decode identically
     to their single-device runs on a 4-way pages-split mesh."""
@@ -335,9 +332,6 @@ def test_sharded_prefix_sharing_and_int8():
         print("SHARDED_COMPOSE_OK")
     """)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=600,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root"},
-                         cwd="/root/repo")
+                         text=True, timeout=600, **cpu_subprocess)
     assert "SHARDED_COMPOSE_OK" in res.stdout, \
         res.stdout[-2000:] + res.stderr[-3000:]

@@ -53,12 +53,13 @@ def _pools(b, t, kh, d, page):
     hist_k = RNG.normal(size=(b, t, kh, d)).astype(np.float32)
     hist_v = RNG.normal(size=(b, t, kh, d)).astype(np.float32)
     mp = t // page
-    kp = np.zeros((b * mp, page, kh, d), np.float32)
+    kp = np.zeros((b * mp, kh, page, d), np.float32)     # head-major
     vp = np.zeros_like(kp)
     for bb in range(b):
         for j in range(mp):
-            kp[int(table[bb, j])] = hist_k[bb, j * page:(j + 1) * page]
-            vp[int(table[bb, j])] = hist_v[bb, j * page:(j + 1) * page]
+            rows = slice(j * page, (j + 1) * page)
+            kp[int(table[bb, j])] = hist_k[bb, rows].swapaxes(0, 1)
+            vp[int(table[bb, j])] = hist_v[bb, rows].swapaxes(0, 1)
     return jnp.asarray(kp), jnp.asarray(vp), table
 
 
@@ -148,11 +149,12 @@ def test_rewind_invalidates_all_page_state():
         for tok in range(16):
             pidx, slot = int(table[0, tok // page]), tok % page
             want = 0 if 6 <= tok < 11 else 1
-            assert (arr[:, pidx, slot] == want).all(), (key, tok)
+            # head-major page state: (L, P, KVH, page[, hd])
+            assert (arr[:, pidx, :, slot] == want).all(), (key, tok)
         # slot 1 untouched
         for tok in range(11):
             pidx, slot = int(table[1, tok // page]), tok % page
-            assert (arr[:, pidx, slot] == 1).all(), (key, tok)
+            assert (arr[:, pidx, :, slot] == 1).all(), (key, tok)
 
 
 # ---------------------------------------------------------------------------

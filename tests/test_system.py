@@ -7,6 +7,11 @@ The paper's claim chain, reproduced as assertions:
   3. the tiling model shows the persistent-A schedule moves fewer HBM bytes
      than the naive one (the paper's bandwidth argument, Table 2).
 """
+import pathlib
+import shutil
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +22,7 @@ from repro.core.tiling import TilePlan, choose_plan
 from repro.models.transformer import apply_model, init_model
 
 KEY = jax.random.PRNGKey(0)
+ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 def test_paper_claim_quantized_qkv_preserves_predictions():
@@ -92,3 +98,39 @@ def test_encdec_memory_reuse():
     memory = encode(params, frames, cfg)
     l2, _, _ = apply_model(params, tokens, cfg, memory=memory)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# entry points: the chip smoke check and the compile cache
+# ---------------------------------------------------------------------------
+def test_chip_smoke_refuses_cpu(cpu_subprocess, tmp_path):
+    """chip_smoke.py fails — and prints no result line — without a TPU,
+    and when it stands alone outside the repository."""
+    res = subprocess.run([sys.executable, str(ROOT / "chip_smoke.py")],
+                         capture_output=True, text=True, timeout=300,
+                         **cpu_subprocess)
+    assert res.returncode != 0 and '"ok"' not in res.stdout, res.stdout
+    assert "no TPU" in res.stderr, res.stderr[-2000:]
+    lone = tmp_path / "chip_smoke.py"
+    shutil.copy(ROOT / "chip_smoke.py", lone)
+    res = subprocess.run([sys.executable, str(lone)], capture_output=True,
+                         text=True, timeout=300, cwd=tmp_path,
+                         env=cpu_subprocess["env"])
+    assert res.returncode != 0 and '"ok"' not in res.stdout, res.stdout
+
+
+def test_compile_cache_location(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache is
+    the fixed, gitignored .jax_cache/ at the checkout root."""
+    from repro.launch import compile_cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *args: updates.append(args))
+    monkeypatch.setenv(compile_cache.ENV, "/var/cache/jax")
+    assert compile_cache.enable_compile_cache() == "/var/cache/jax"
+    assert updates == []
+    monkeypatch.delenv(compile_cache.ENV)
+    here = str(ROOT / ".jax_cache")
+    assert compile_cache.enable_compile_cache() == here
+    assert updates == [("jax_compilation_cache_dir", here)]
+    assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()
