@@ -42,14 +42,12 @@ mesh variants only apply to page-pool families.
 
 Reported per row: generated tokens/s (host wall time — ordering-only on
 CPU, see benchmarks/common.py), decode steps taken, page/slot-pool
-occupancy (peak / mean over ticks vs the pool size; sharded rows add
-``shard_peaks``, the per-shard page peaks — the fullest shard is what
-admission actually gates on), and request-level latency percentiles:
-TTFT (submit → first token, p50/p95) and per-token decode latency
-(p50/p95), joined from the scheduler's request event log and per-tick
-wall times.  The occupancy columns are exact regardless of host timing:
-they count pages through the allocator, the serving analogue of the
-flash engine's blocks-touched counters.
+occupancy (peak / mean over ticks vs the pool size), and request-level
+latency percentiles: TTFT (submit → first token, p50/p95) and per-token
+decode latency (p50/p95), joined from the scheduler's request event log
+and per-tick wall times.  The occupancy columns are exact regardless of
+host timing: they count pages through the allocator, the serving
+analogue of the flash engine's blocks-touched counters.
 
 Run: ``python -m benchmarks.serving [--smoke] [--json PATH] [--mesh N]``.
 """
@@ -153,11 +151,9 @@ def _run_continuous(params, cfg, reqs, *, slots, pool, page, max_len,
     sec = time.perf_counter() - t0
     n_tokens = sum(len(v) for v in sched.finished.values())
     occ = np.asarray(sched.occupancy_log)
-    shard_occ = np.asarray(sched.shard_occupancy_log)   # (ticks, S)
     out = {"wall_s": sec, "tokens": n_tokens, "steps": tick,
            "pages_peak": int(occ.max()), "pages_mean": float(occ.mean()),
            "pool": sched.pool_occupancy().total,
-           "shard_peaks": [int(p) for p in shard_occ.max(axis=0)],
            "page_bytes": (page_nbytes(sched.cache)
                           if "k_pages" in sched.cache else None),
            "finished": sched.finished,
@@ -233,7 +229,7 @@ def _run_static(params, cfg, reqs, *, slots, page, max_len):
     occ = np.asarray(occ)
     return {"wall_s": sec, "tokens": n_tokens, "steps": steps,
             "pages_peak": int(occ.max()), "pages_mean": float(occ.mean()),
-            "pool": len(reqs[:slots]) * max_pages, "shard_peaks": None,
+            "pool": len(reqs[:slots]) * max_pages,
             "page_bytes": pb}
 
 
@@ -306,7 +302,6 @@ def bench_one(name, arch, slots, pool, page, max_len, n_requests, seed,
             "pages_mean": round(res["pages_mean"], 1),
             "pool_pages": res["pool"],
             "occupancy_frac": round(res["pages_mean"] / res["pool"], 3),
-            "shard_peaks": res["shard_peaks"],
             "page_bytes": res["page_bytes"],
             "tokens_per_step": res.get("tokens_per_step"),
             "accept_rate": res.get("accept_rate"),

@@ -396,9 +396,13 @@ def paged_decode_kernel(q: jax.Array, k_pages: jax.Array,
             pltpu.VMEM((g * qc, 1), jnp.float32),
         ],
     )
+    # ``name`` opens a named scope around the call, so under every caller
+    # (prefill chunks, the decode tick) the compiled custom call, and the
+    # profiler trace, name it ``paged_flash.N`` and not after an
+    # enclosing remat ``checkpoint.N``; the other kernels do the same
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, qs, d), out_dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_flash",
     )(*scalars, *operands)
     return out.reshape(b, h, qs, d)

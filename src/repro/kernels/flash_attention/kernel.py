@@ -251,5 +251,5 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((qc, 1), jnp.float32),
             pltpu.VMEM((qc, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_prefill",
     )(q, k, v)

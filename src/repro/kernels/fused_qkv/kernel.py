@@ -220,5 +220,5 @@ def fused_qkv_kernel(a_values, a_scale, wq, sq, wk, sk, wv, sv, *,
         ),
         scratch_shapes=scratch_shapes,
         compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
+        interpret=interpret, name="fused_qkv_int8",
     )(a_values, wq, wk, wv, a_scale, sq, sk, sv)

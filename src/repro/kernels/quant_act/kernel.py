@@ -60,5 +60,5 @@ def quant_act_kernel(x: jax.Array, *, block_m: int = 256, qmax: int = 127,
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret,
+        interpret=interpret, name="quant_act",
     )(x)

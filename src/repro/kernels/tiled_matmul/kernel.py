@@ -161,7 +161,7 @@ def tiled_matmul_kernel(a_values: jax.Array, a_scale: jax.Array,
             out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
             out_shape=out_shape,
             compiler_params=_COMPILER_PARAMS,
-            interpret=interpret,
+            interpret=interpret, name="int8_gemm_panel",
         )(*operands)
 
     grid = (ceil_div(m, block_m), ceil_div(n, block_n), k_steps)
@@ -185,5 +185,5 @@ def tiled_matmul_kernel(a_values: jax.Array, a_scale: jax.Array,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
+        interpret=interpret, name="int8_gemm_ksplit",
     )(*operands)

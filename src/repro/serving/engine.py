@@ -30,6 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.tiled_matmul.ops import replicated_operands
 from repro.launch.sharding import activate_sharding
@@ -242,28 +243,30 @@ def prefill(params: Params, cache: dict, prompts: jax.Array,
     for c0 in range(0, s_pad, width):
         cs = min(width, s_pad - c0)
         pos = start_pos + c0
-        if memory is None:
-            got, cache = _prefill_run(
-                params, cache, prompts[:, c0:c0 + cs], prompt_lens,
-                jnp.asarray(pos, jnp.int32), cfg, kernel_mode(), mesh)
-        else:
-            # encoder-decoder: the cross-attention memory step runs eagerly
-            nv = jnp.clip(prompt_lens - pos, 0, cs) if is_ssm else None
-            with _mesh_context(mesh):
-                logits, cache, _ = apply_model(
-                    params, prompts[:, c0:c0 + cs], cfg, cache=cache,
-                    cache_pos=jnp.full((b,), pos, jnp.int32), memory=memory,
-                    n_valid=nv)
-            got = jnp.take_along_axis(
-                logits, jnp.clip(prompt_lens - 1 - pos, 0, cs - 1)[:, None,
-                                                                   None],
-                axis=1)[:, 0]
-        # each sequence's last real prompt token lives in exactly one
-        # chunk: harvest its logits as that chunk goes by
-        rel = prompt_lens - 1 - pos
-        inside = (rel >= 0) & (rel < cs)
-        next_logits = (got if next_logits is None
-                       else jnp.where(inside[:, None], got, next_logits))
+        with TraceAnnotation("serving.prefill.chunk", start=pos, width=cs):
+            if memory is None:
+                got, cache = _prefill_run(
+                    params, cache, prompts[:, c0:c0 + cs], prompt_lens,
+                    jnp.asarray(pos, jnp.int32), cfg, kernel_mode(), mesh)
+            else:
+                # encoder-decoder: the cross-attention memory step runs
+                # eagerly
+                nv = jnp.clip(prompt_lens - pos, 0, cs) if is_ssm else None
+                with _mesh_context(mesh):
+                    logits, cache, _ = apply_model(
+                        params, prompts[:, c0:c0 + cs], cfg, cache=cache,
+                        cache_pos=jnp.full((b,), pos, jnp.int32),
+                        memory=memory, n_valid=nv)
+                got = jnp.take_along_axis(
+                    logits, jnp.clip(prompt_lens - 1 - pos, 0,
+                                     cs - 1)[:, None, None],
+                    axis=1)[:, 0]
+            # each sequence's last real prompt token lives in exactly one
+            # chunk: harvest its logits as that chunk goes by
+            rel = prompt_lens - 1 - pos
+            inside = (rel >= 0) & (rel < cs)
+            next_logits = (got if next_logits is None
+                           else jnp.where(inside[:, None], got, next_logits))
     if "seq_lens" in cache:
         # padded tails were written but are NOT committed: visibility is
         # governed by seq_lens, and decode overwrites them slot by slot.

@@ -50,6 +50,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ModelConfig
 from repro.serving.cache import CacheConfig, cache_shardings, init_cache
@@ -242,8 +243,8 @@ class Scheduler:
         # these against per-tick wall times
         self.request_log: dict[int, dict] = {}
         self.occupancy_log: list[int] = []
-        self.shard_occupancy_log: list[tuple[int, ...]] = []
         self._next_rid = 0
+        self._admitting_rid = -1     # rid of the admission in progress
         self._ticks = 0
 
     # -- request intake ----------------------------------------------------
@@ -296,14 +297,20 @@ class Scheduler:
         """One scheduler tick: admit from the queue, run one decode step
         for the live batch, retire rows that just finished (their pages
         return to the pool before the next tick's admissions).  Returns
-        the ids of requests that finished this tick."""
-        self._admit()
-        self._decode()
-        done = self._retire()
-        self._ticks += 1
-        occ = self.pool_occupancy()
-        self.occupancy_log.append(occ.used)
-        self.shard_occupancy_log.append(tuple(u for u, _ in occ.per_shard))
+        the ids of requests that finished this tick.
+
+        Each layer of the tick is a ``serving.*`` span in the profiler's
+        trace (``docs/DESIGN.md`` §6); with no profiler running a span
+        records nothing."""
+        with TraceAnnotation("serving.step", tick=self._ticks):
+            self._admit()
+            self._decode()
+            done = self._retire()
+            self._ticks += 1
+            with TraceAnnotation("serving.occupancy") as span:
+                used = self.handler.used(self.cache)
+                span.set_metadata(pages_used=used)
+            self.occupancy_log.append(used)
         return done
 
     def run(self, max_ticks: int | None = None) -> dict[int, np.ndarray]:
@@ -327,18 +334,20 @@ class Scheduler:
 
     def _retire(self) -> list[int]:
         done = []
-        for b, slot in enumerate(self.slots):
-            if slot is not None and self._finished(slot):
-                self.cache = self.handler.free(self.cache, b)
-                if self.spec is not None:
-                    self.draft_cache = self.handler.draft_free(
-                        self.draft_cache, b)
-                self.finished[slot.req.rid] = np.asarray(slot.generated,
-                                                         np.int32)
-                self.request_log[slot.req.rid].update(
-                    admitted=slot.admitted, token_ticks=slot.token_ticks)
-                done.append(slot.req.rid)
-                self.slots[b] = None
+        with TraceAnnotation("serving.retire") as span:
+            for b, slot in enumerate(self.slots):
+                if slot is not None and self._finished(slot):
+                    self.cache = self.handler.free(self.cache, b)
+                    if self.spec is not None:
+                        self.draft_cache = self.handler.draft_free(
+                            self.draft_cache, b)
+                    self.finished[slot.req.rid] = np.asarray(slot.generated,
+                                                             np.int32)
+                    self.request_log[slot.req.rid].update(
+                        admitted=slot.admitted, token_ticks=slot.token_ticks)
+                    done.append(slot.req.rid)
+                    self.slots[b] = None
+            span.set_metadata(finished=len(done))
         return done
 
     def _prefix_match(self, prompt: np.ndarray):
@@ -373,25 +382,34 @@ class Scheduler:
             parent, shared = (-1, 0)
             if self.share_prefix and self.handler.supports_prefix_sharing:
                 parent, shared = self._prefix_match(req.prompt)
-            if shared > 0:
-                self.cache, ok = self.handler.fork(
-                    self.cache, parent, b, shared, budget)
-                if bool(ok) and self.spec is not None:
-                    # the child wakes with the parent's committed prefix:
-                    # the draft model must see the same context
-                    self.draft_cache = self.handler.draft_fork(
-                        self.draft_cache, parent, b)
-            else:
-                self.cache, ok = self.handler.admit(self.cache, b, budget)
-            if not bool(ok):
-                if self.n_active == 0:
-                    raise RuntimeError(
-                        f"request {req.rid} needs more pages than an empty "
-                        f"pool of {self.pool_occupancy()[1]} offers")
-                return                       # pool full: wait for retires
-            self.queue.popleft()
-            first = int(jnp.argmax(
-                self._prefill_slot(b, req.prompt, start=shared)))
+            # one span per attempt: the claim, and once it is granted the
+            # prefill and the first token's read
+            with TraceAnnotation("serving.admit", rid=req.rid,
+                                 prompt_tokens=req.prompt.size,
+                                 shared_tokens=shared):
+                if shared > 0:
+                    self.cache, ok = self.handler.fork(
+                        self.cache, parent, b, shared, budget)
+                    if bool(ok) and self.spec is not None:
+                        # the child wakes with the parent's committed
+                        # prefix: the draft model must see the same context
+                        self.draft_cache = self.handler.draft_fork(
+                            self.draft_cache, parent, b)
+                else:
+                    self.cache, ok = self.handler.admit(self.cache, b,
+                                                        budget)
+                if not bool(ok):
+                    if self.n_active == 0:
+                        raise RuntimeError(
+                            f"request {req.rid} needs more pages than an "
+                            f"empty pool of {self.pool_occupancy()[1]} "
+                            f"offers")
+                    return                   # pool full: wait for retires
+                self.queue.popleft()
+                self._admitting_rid = req.rid
+                logits = self._prefill_slot(b, req.prompt, start=shared)
+                with TraceAnnotation("serving.first_token", rid=req.rid):
+                    first = int(jnp.argmax(logits))
             self.slots[b] = _Slot(req, [first], first,
                                   admitted=self._ticks,
                                   token_ticks=[self._ticks])
@@ -404,25 +422,27 @@ class Scheduler:
         suffix = prompt[start:]
         pad = -suffix.size % self.bucket
         padded = np.pad(suffix, (0, pad))
-        view = self.handler.slot_view(self.cache, b)
-        nl, view = prefill(
-            self.params, view, jnp.asarray(padded[None]),
-            jnp.asarray([prompt.size], jnp.int32), self.cfg,
-            chunk=self.prefill_chunk, start_pos=start,
-            config=self.config)
-        self.cache = self.handler.merge_slot(self.cache, view, b)
-        if self.spec is not None:
-            # commit the prompt into the draft model's dense row too (the
-            # prefix-shared part was copied by draft_fork; only the
-            # suffix runs), one fused jitted call per admission
-            self.draft_cache = draft_prefill_row(
-                self.spec.draft_params, self.draft_cache,
-                jnp.asarray(padded[None]),
-                jnp.asarray([prompt.size], jnp.int32),
-                jnp.asarray(start, jnp.int32), jnp.asarray(b, jnp.int32),
-                self.spec.draft_cfg, kernel_mode())
-        self._pin_shardings()
-        return nl[0]
+        with TraceAnnotation("serving.prefill", rid=self._admitting_rid,
+                             tokens=suffix.size, padded=padded.size):
+            view = self.handler.slot_view(self.cache, b)
+            nl, view = prefill(
+                self.params, view, jnp.asarray(padded[None]),
+                jnp.asarray([prompt.size], jnp.int32), self.cfg,
+                chunk=self.prefill_chunk, start_pos=start,
+                config=self.config)
+            self.cache = self.handler.merge_slot(self.cache, view, b)
+            if self.spec is not None:
+                # commit the prompt into the draft model's dense row too
+                # (the prefix-shared part was copied by draft_fork; only
+                # the suffix runs), one fused jitted call per admission
+                self.draft_cache = draft_prefill_row(
+                    self.spec.draft_params, self.draft_cache,
+                    jnp.asarray(padded[None]),
+                    jnp.asarray([prompt.size], jnp.int32),
+                    jnp.asarray(start, jnp.int32), jnp.asarray(b, jnp.int32),
+                    self.spec.draft_cfg, kernel_mode())
+            self._pin_shardings()
+            return nl[0]
 
     def _pin_shardings(self):
         """Re-place cache leaves on their expected shardings (mesh only).
@@ -444,28 +464,32 @@ class Scheduler:
             self._spec_decode()
             return
         from repro.kernels.tiled_matmul.ops import kernel_mode
-        active = np.asarray([s is not None for s in self.slots])
-        tok = jnp.asarray([[s.last_token if s else 0] for s in self.slots],
-                          jnp.int32)
-        # the donated cache must arrive partitioned exactly as compiled —
-        # eager retire/admit scatters since the last tick may have moved
-        # placements
-        self._pin_shardings()
-        # the static-batch loop's own jitted scan body, n_steps=1: one
-        # compile shared with greedy_decode, cache donated in and out
-        toks, self.cache = _greedy_run(
-            self.params, self.cache, tok, jnp.asarray(0, jnp.int32), None,
-            self.cfg, 1, True, kernel_mode(), self.config.mesh)
-        nxt = np.asarray(toks)[0, :, 0]
-        # idle rows advanced their (zero) lengths and wrote garbage to
-        # their scratch targets; the handler re-pins them so an idle
-        # row's masked walk never grows
-        self.cache = self.handler.advance(self.cache, active)
-        for b, slot in enumerate(self.slots):
-            if slot is not None and not self._finished(slot):
-                slot.last_token = int(nxt[b])
-                slot.generated.append(slot.last_token)
-                slot.token_ticks.append(self._ticks)
+        with TraceAnnotation("serving.decode", tick=self._ticks,
+                             live=self.n_active):
+            active = np.asarray([s is not None for s in self.slots])
+            tok = jnp.asarray([[s.last_token if s else 0]
+                               for s in self.slots], jnp.int32)
+            # the donated cache must arrive partitioned exactly as
+            # compiled — eager retire/admit scatters since the last tick
+            # may have moved placements
+            self._pin_shardings()
+            # the static-batch loop's own jitted scan body, n_steps=1: one
+            # compile shared with greedy_decode, cache donated in and out
+            toks, self.cache = _greedy_run(
+                self.params, self.cache, tok, jnp.asarray(0, jnp.int32),
+                None, self.cfg, 1, True, kernel_mode(), self.config.mesh)
+            with TraceAnnotation("serving.decode.wait"):
+                nxt = np.asarray(toks)[0, :, 0]
+            with TraceAnnotation("serving.decode.advance"):
+                # idle rows advanced their (zero) lengths and wrote
+                # garbage to their scratch targets; the handler re-pins
+                # them so an idle row's masked walk never grows
+                self.cache = self.handler.advance(self.cache, active)
+                for b, slot in enumerate(self.slots):
+                    if slot is not None and not self._finished(slot):
+                        slot.last_token = int(nxt[b])
+                        slot.generated.append(slot.last_token)
+                        slot.token_ticks.append(self._ticks)
 
     def _spec_decode(self):
         """One draft-and-verify tick (``engine.spec_step``): each live
@@ -475,33 +499,39 @@ class Scheduler:
         token, so a multi-accept step contributes that many entries at
         the same tick and the latency percentiles stay per-token."""
         spec = self.spec
-        active = np.asarray([s is not None for s in self.slots])
-        tok = jnp.asarray([[s.last_token if s else 0] for s in self.slots],
-                          jnp.int32)
-        # rows at budget already (e.g. admitted this tick with an
-        # exhausted budget) emit 0 and roll their whole verify back
-        budget_left = jnp.asarray(
-            [s.req.max_new_tokens - len(s.generated) if s else 0
-             for s in self.slots], jnp.int32)
-        self._pin_shardings()
-        pred, m, acc, self.cache, self.draft_cache = spec_step(
-            self.params, spec.draft_params, self.cache, self.draft_cache,
-            tok, budget_left, jnp.asarray(active), self.cfg,
-            spec.draft_cfg, n_draft=spec.n_draft, eos_id=self.eos_id,
-            config=self.config)
-        pred, m, acc = np.asarray(pred), np.asarray(m), np.asarray(acc)
-        self.cache = self.handler.advance(self.cache, active)
-        st = self.spec_stats
-        st["ticks"] += 1
-        st["proposed"] += int(active.sum()) * spec.n_draft
-        st["emitted"] += int(m.sum())
-        # accepted = emitted tokens that were draft proposals (min(k, m)
-        # in-engine: on a full match every emitted token is a draft)
-        st["accepted"] += int(acc.sum())
-        for b, slot in enumerate(self.slots):
-            if slot is None or not m[b]:
-                continue
-            emitted = [int(t) for t in pred[b, :m[b]]]
-            slot.generated.extend(emitted)
-            slot.token_ticks.extend([self._ticks] * len(emitted))
-            slot.last_token = emitted[-1]
+        with TraceAnnotation("serving.decode", tick=self._ticks,
+                             live=self.n_active):
+            active = np.asarray([s is not None for s in self.slots])
+            tok = jnp.asarray([[s.last_token if s else 0]
+                               for s in self.slots], jnp.int32)
+            # rows at budget already (e.g. admitted this tick with an
+            # exhausted budget) emit 0 and roll their whole verify back
+            budget_left = jnp.asarray(
+                [s.req.max_new_tokens - len(s.generated) if s else 0
+                 for s in self.slots], jnp.int32)
+            self._pin_shardings()
+            pred, m, acc, self.cache, self.draft_cache = spec_step(
+                self.params, spec.draft_params, self.cache,
+                self.draft_cache, tok, budget_left, jnp.asarray(active),
+                self.cfg, spec.draft_cfg, n_draft=spec.n_draft,
+                eos_id=self.eos_id, config=self.config)
+            with TraceAnnotation("serving.decode.wait"):
+                pred, m, acc = (np.asarray(pred), np.asarray(m),
+                                np.asarray(acc))
+            with TraceAnnotation("serving.decode.advance"):
+                self.cache = self.handler.advance(self.cache, active)
+                st = self.spec_stats
+                st["ticks"] += 1
+                st["proposed"] += int(active.sum()) * spec.n_draft
+                st["emitted"] += int(m.sum())
+                # accepted = emitted tokens that were draft proposals
+                # (min(k, m) in-engine: on a full match every emitted
+                # token is a draft)
+                st["accepted"] += int(acc.sum())
+                for b, slot in enumerate(self.slots):
+                    if slot is None or not m[b]:
+                        continue
+                    emitted = [int(t) for t in pred[b, :m[b]]]
+                    slot.generated.extend(emitted)
+                    slot.token_ticks.extend([self._ticks] * len(emitted))
+                    slot.last_token = emitted[-1]

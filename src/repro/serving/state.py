@@ -93,6 +93,11 @@ class StateHandler:
         (pages for ``paged_kv``, batch slots for the slot families)."""
         raise NotImplementedError
 
+    def used(self, cache: dict) -> int:
+        """``occupancy``'s ``used`` alone: what the scheduler samples
+        every tick."""
+        return self.occupancy(cache)[0]
+
     # -- admission lifecycle ----------------------------------------------
     def admit(self, cache: dict, slot: int, n_tokens: int):
         """Claim state for a sequence of up to ``n_tokens`` tokens in
@@ -176,6 +181,9 @@ class PagedKVHandler(StateHandler):
     def occupancy(self, cache):
         used, total = alloc.pool_occupancy(cache)
         return used, total, alloc.shard_occupancy(cache)
+
+    def used(self, cache):
+        return alloc.pool_occupancy(cache)[0]
 
     def admit(self, cache, slot, n_tokens):
         return alloc.admit_sequence(cache, slot, n_tokens)

@@ -172,3 +172,43 @@ def test_kernels_replicate_only_under_serving(topo):
 
     assert "shard_map" not in traced(activate_sharding(mesh, SERVING_RULES))
     assert traced(_mesh_context(mesh)).count("shard_map") == 2
+
+
+_GEMM = (_qtensor(64, 768, 0), _qtensor(768, 3072, 1))
+# one case per pallas_call site: (kernel name, call, argument shapes)
+KERNEL_SITES = {
+    "paged_flash": (functools.partial(paged_decode_attention,
+                                      mode="pallas"), (
+        _sds((4, 1, QWEN.n_heads, QWEN.head_dim), jnp.bfloat16),
+        _sds((69, QWEN.n_kv_heads, PAGE, QWEN.head_dim), jnp.bfloat16),
+        _sds((69, QWEN.n_kv_heads, PAGE, QWEN.head_dim), jnp.bfloat16),
+        _sds((4, 17), jnp.int32), _sds((4,), jnp.int32))),
+    "flash_prefill": (functools.partial(flash_attention, mode="pallas"), (
+        _sds((1, 512, QWEN.n_heads, QWEN.head_dim), jnp.bfloat16),
+        _sds((1, 512, QWEN.n_kv_heads, QWEN.head_dim), jnp.bfloat16),
+        _sds((1, 512, QWEN.n_kv_heads, QWEN.head_dim), jnp.bfloat16))),
+    "fused_qkv_int8": (functools.partial(fused_qkv, mode="pallas"), (
+        _qtensor(8, QWEN.d_model, 0),
+        _qtensor(QWEN.d_model, QWEN.q_dim, 1),
+        _qtensor(QWEN.d_model, QWEN.kv_dim, 1),
+        _qtensor(QWEN.d_model, QWEN.kv_dim, 1))),
+    "quant_act": (functools.partial(quant_act, mode="pallas"), (
+        _sds((256, QWEN.d_model), jnp.bfloat16),)),
+    "int8_gemm_panel": (functools.partial(
+        tiled_matmul, block_m=64, block_n=256, mode="pallas"), _GEMM),
+    "int8_gemm_ksplit": (functools.partial(
+        tiled_matmul, block_m=64, block_n=256, block_k=256, mode="pallas"),
+        _GEMM),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_SITES))
+def test_kernel_is_named_in_the_compiled_program(one_chip, name):
+    """Each kernel's custom call carries the kernel's name, also under an
+    enclosing remat (which would otherwise name it ``checkpoint.N``), so
+    the device trace finds it by that name."""
+    fn, shapes = KERNEL_SITES[name]
+    text = _compile(jax.checkpoint(fn), one_chip, *shapes).as_text()
+    calls = [line.split(" = ")[0].split()[-1] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(c.startswith(f"%{name}.") for c in calls), calls
