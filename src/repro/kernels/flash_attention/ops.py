@@ -91,7 +91,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
 
     Lowers to the paged flash kernel (``decode.py``) under
     ``pallas``/``pallas_interpret`` — a length-aware page walk that
-    streams each KV-head's occupied pages once per query group — and to
+    streams each sequence's occupied pages, every KV head at once, in
+    blocks of pages — and to
     the dense gather oracle ``ref.paged_attention_ref`` under ``ref``.
     """
     mode = mode or kernel_mode()

@@ -386,9 +386,8 @@ def _paged_attend_tp(q, tok_pos, page_table, lengths, pools,
     partitions identically).  Each shard runs the *full* schedule —
     kernel page walk or gather oracle — over its head slice and the
     complete page table; softmax is per-head, so no combine is needed and
-    per-head math is identical to the unsharded path.  This is the
-    ``(B·KVH, q_blocks, steps)`` kernel grid partitioned over its KVH
-    factor."""
+    per-head math is identical to the unsharded path: each shard's
+    kernel streams its own KV-head slice of every page."""
     from jax.sharding import PartitionSpec as P
     quant = len(pools) == 4
     pool_specs = _pool_specs(quant, "heads")

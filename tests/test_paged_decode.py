@@ -16,6 +16,7 @@ Three layers of coverage:
     behaviour; gemma2's traced local/global layers decode identically on
     both layouts; interpret-mode kernel end-to-end through ``serve_step``.
 """
+import functools
 import itertools
 
 import jax
@@ -24,8 +25,11 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.kernels.flash_attention.decode import (flash_decode_schedule,
-                                                 pages_touched)
+from repro.core.tiling import VMEM_PLAN_BUDGET
+from repro.kernels.flash_attention.decode import (MAX_PAGES_PER_BLOCK,
+                                                 blocks_touched,
+                                                 flash_decode_schedule,
+                                                 grid_steps, pages_touched)
 from repro.kernels.flash_attention.ops import paged_decode_attention
 from repro.kernels.flash_attention.ref import paged_gather
 from repro.models.transformer import init_model
@@ -128,6 +132,81 @@ def test_paged_decode_parity_sweep(g, window, page, lens, cap):
     _case(2, 128, h, h // g, 64, page, lens, window=window, cap=cap)
 
 
+# Block edges of the page-block walk.  Page 8 over a 40-page table gives
+# 16-page blocks (``pages_per_block`` is asserted below, so a different
+# block size fails loudly instead of testing other edges); each case is
+# (lens, extra keywords, the block size it needs).
+EDGE_PAGE, EDGE_PAGES = 8, 40
+BLOCK_EDGES = {
+    # 100 → 13 pages of block 0; 200 → 25 pages, 9 into block 1
+    "ends_mid_block": ([100, 200], {}, 16),
+    # exactly one and exactly two whole blocks of pages
+    "whole_blocks": ([128, 256], {}, 16),
+    # 17 and 33 pages: the last block holds a single page
+    "one_page_block": ([136, 264], {}, 16),
+    # 20 tokens: blocks 1 and 2 lie wholly past j_hi
+    "blocks_past_j_hi": ([20, 320], {}, 16),
+    # length 0 on the scratch page beside a live row
+    "idle_row": ([0, 77], {"idle": True}, 16),
+    # a 200-token window: j_lo = page 12 (token 101, mid-page), the
+    # 26-page walk spans a 16-page block and a partial one
+    "window_j_lo_mid_page": ([300, 57], {"window": 200}, 16),
+    # int8 pools with their scale rows, across both blocks and idle
+    "int8_pools": ([200, 0, 128], {"quant": True, "idle": True}, 16),
+    # verify mode: new_lens of 0 (every row dead) and the full count
+    "verify_new_lens": ([130, 250], {"qs": 4, "new_lens": [0, 4]}, 16),
+    # chunked prefill: 32 rows as two 16-row q blocks, each walking to
+    # its own causal horizon
+    "prefill_two_q_blocks": ([128, 301], {"qs": 32, "q_chunk": 16}, 16),
+}
+
+
+@pytest.mark.parametrize("edge", sorted(BLOCK_EDGES))
+def test_paged_decode_block_edges(edge):
+    """The kernel (interpret mode) against ``paged_attention_ref`` where
+    the page-block walk turns: partial, whole and skipped blocks, idle
+    rows, windows, int8 pools, verify rows and multi-q-block steps."""
+    from repro.core.quantization import quantize_kv
+
+    lens, kw, ppb = BLOCK_EDGES[edge]
+    b, h, kh, d = len(lens), 4, 2, 64
+    qs, q_chunk = kw.get("qs", 1), kw.get("q_chunk")
+    window = kw.get("window")
+    table = default_page_table(b, EDGE_PAGES, "striped")
+    if kw.get("idle"):
+        # idle rows point every page at the scratch page (id 0)
+        table = jnp.where(jnp.asarray(lens)[:, None] == 0, 0, table)
+    hist = RNG.normal(size=(2, b, EDGE_PAGES * EDGE_PAGE, kh, d))
+    kp, vp = _pools_from_history(hist[0].astype(np.float32),
+                                 hist[1].astype(np.float32), EDGE_PAGE,
+                                 default_page_table(b, EDGE_PAGES,
+                                                    "striped"))
+    scales = {}
+    if kw.get("quant"):
+        (kp, ks), (vp, vs) = quantize_kv(kp), quantize_kv(vp)
+        scales = {"k_scales": ks, "v_scales": vs}
+    new_lens = kw.get("new_lens")
+    if new_lens is not None:
+        scales["new_lens"] = jnp.asarray(new_lens, jnp.int32)
+    q = jnp.asarray(RNG.normal(size=(b, qs, h, d)).astype(np.float32))
+    lens = jnp.asarray(lens, jnp.int32)
+
+    sched = flash_decode_schedule(
+        EDGE_PAGES, EDGE_PAGE, q_len=qs, window=window, q_chunk=q_chunk,
+        group=h // kh, kv_heads=kh, head_dim=d, kv_dtype=kp.dtype)
+    assert sched.pages_per_block == ppb, sched
+    out = paged_decode_attention(q, kp, vp, table, lens, window=window,
+                                 q_chunk=q_chunk, mode="pallas_interpret",
+                                 **scales)
+    want = paged_decode_attention(q, kp, vp, table, lens, window=window,
+                                  mode="ref", **scales)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=5e-6, rtol=1e-5)
+    if kw.get("idle"):
+        np.testing.assert_array_equal(np.asarray(out)[np.asarray(lens) == 0],
+                                      0.0)
+
+
 def test_paged_gather_roundtrip():
     table = default_page_table(2, 4, "striped")
     hist = RNG.normal(size=(2, 32, 2, 8)).astype(np.float32)
@@ -170,6 +249,51 @@ def test_decode_pages_touched_counters():
     scw = flash_decode_schedule(8, 16, q_len=1, window=20)
     # windowed: at most ceil((1+19)/16)+1 = 3 pages per sequence
     assert pages_touched([37, 5, 128], scw) == 2 + 1 + 2
+
+
+def test_decode_grid_steps_and_blocks_touched():
+    """Launched grid steps against the page blocks that stream, by hand
+    and at the served geometry."""
+    sc = flash_decode_schedule(40, 8, group=2, kv_heads=2, head_dim=64)
+    assert (sc.pages_per_block, sc.num_blocks) == (16, 3)   # 16+16+8
+    assert grid_steps(sc, 3) == 3 * 1 * 3
+    # 100 → 13 pages (1 block), 128 → 16 (1), 0 → page 0 (1),
+    # 200 → 25 (2), 320 → 40 (3)
+    assert blocks_touched([100, 128, 0], sc) == 1 + 1 + 1
+    assert blocks_touched([200, 320], sc) == 2 + 3
+    # a 2x16-row prefill chunk: each q block walks to its own horizon
+    scp = flash_decode_schedule(40, 8, q_len=32, q_chunk=16, group=2,
+                                kv_heads=2, head_dim=64)
+    assert grid_steps(scp, 2) == 2 * 2 * scp.num_blocks
+    # ctx 129: block 0 ends at token 112 (page 14), block 1 at 128
+    # (page 16, the second block); ctx 40: pages 0-2 and 0-4
+    assert blocks_touched([129, 40], scp) == (1 + 2) + (1 + 1)
+
+    # qwen2.5-3B served: 64 rows, an 80-page table of 64-token pages,
+    # 2 KV heads x 8 query heads x 128, bf16.  Before: a (B·KH, 1, 80)
+    # grid of one (page, D) tile a step, 10,240 steps a layer
+    cell = flash_decode_schedule(80, 64, group=8, kv_heads=2, head_dim=128)
+    assert 8 <= cell.pages_per_block <= 16
+    launched = grid_steps(cell, 64)
+    assert launched <= 640 and 64 * 2 * 80 >= 16 * launched
+    # ~44 live rows of ~950 tokens (15 pages) and 20 idle rows
+    lens = [950] * 44 + [0] * 20
+    assert pages_touched(lens, cell) == 44 * 15 + 20
+    assert blocks_touched(lens, cell) == 44 + 20
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
+def test_decode_block_fits_vmem(kv_dtype):
+    """The page-block size is fitted to the VMEM budget: a decode step's
+    8 q rows a head get a large block, a 128-row prefill q block (1,024
+    rows a head) a smaller one, and both fit."""
+    plan = functools.partial(flash_decode_schedule, 80, 64, group=8,
+                             kv_heads=2, head_dim=128, kv_dtype=kv_dtype)
+    decode, prefill = plan(), plan(q_len=256, q_chunk=128)
+    for sc in (decode, prefill):
+        assert sc.vmem_bytes <= VMEM_PLAN_BUDGET, sc
+    assert decode.pages_per_block == MAX_PAGES_PER_BLOCK
+    assert 1 <= prefill.pages_per_block < decode.pages_per_block
 
 
 # ---------------------------------------------------------------------------

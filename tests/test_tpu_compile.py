@@ -109,11 +109,17 @@ def test_flash_prefill_compiles(one_chip):
 
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])
-@pytest.mark.parametrize("batch,rows,q_chunk", [(4, 1, None),
-                                                (1, 256, 128)])
-def test_paged_kernel_compiles(one_chip, kv_quant, batch, rows, q_chunk):
+@pytest.mark.parametrize("batch,rows,q_chunk,max_pages,n_pages", [
+    pytest.param(4, 1, None, 17, 69, id="4-1-None"),
+    pytest.param(1, 256, 128, 17, 69, id="1-256-128"),
+    # the served geometry: 64 slots, an 80-page table (max_len 5120) and
+    # a 1,536-page pool; decode and one 256-row prefill chunk
+    pytest.param(64, 1, None, 80, 1536, id="served-decode"),
+    pytest.param(1, 256, 128, 80, 1536, id="served-prefill"),
+])
+def test_paged_kernel_compiles(one_chip, kv_quant, batch, rows, q_chunk,
+                               max_pages, n_pages):
     h, kh, hd = QWEN.n_heads, QWEN.n_kv_heads, QWEN.head_dim
-    max_pages, n_pages = 17, 69
     pool = _sds((n_pages, kh, PAGE, hd),
                 jnp.int8 if kv_quant == "int8" else jnp.bfloat16)
     scales = (_sds((n_pages, kh, PAGE), jnp.float32),) * 2 \
