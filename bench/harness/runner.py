@@ -16,6 +16,11 @@ window ran on must be stored as the configuration states (``judge``).
 With ``--trace 1`` the profiler records the window's last seconds and the
 drain; the per-layer metrics are read from that run.
 
+Nothing here knows the block's structure: the program config's check,
+the weights and what the readers count come from the cell's architecture
+module (``architectures/<reference>.py``, ``spec.architecture``), which
+``prepare`` loads once and every later step is handed.
+
 The last line of standard output is one JSON object; the numbers compared
 are the last lines of standard error and the last key of that object.
 """
@@ -29,7 +34,7 @@ import sys
 
 import numpy as np
 
-from harness import costs, devtrace, serve, spec, stats, traffic, weights
+from harness import devtrace, serve, spec, stats, traffic
 
 TRACE_SECONDS = 3.0     # length of the traced slice at the window's end
 SAMPLE_TOKENS = 384     # served tokens the reference reads, at least
@@ -68,28 +73,6 @@ def enable_compile_cache() -> str:
     return enable()
 
 
-def program_config(config: dict):
-    """The program's model config as the configuration file states it;
-    refuses one whose widths differ from the file's ``shapes``."""
-    from repro.configs import get_config
-
-    cfg = get_config(config["model"]).replace(**config["overrides"])
-    s = config["shapes"]
-    have = {"layers": cfg.n_layers, "d_model": cfg.d_model,
-            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
-            "vocab": cfg.vocab_size, "tied_embeddings": cfg.tie_embeddings,
-            "qkv_bias": cfg.qkv_bias, "norm_eps": cfg.norm_eps,
-            "rope_theta": cfg.rope_theta,
-            "rope_fraction": (cfg.rope_fraction
-                              if cfg.rope_style == "partial" else 1.0)}
-    bad = {k: (v, s[k]) for k, v in have.items() if v != s[k]}
-    if bad or cfg.ffn_type != "swiglu" or cfg.norm_type != "rmsnorm":
-        raise ValueError(f"program config {cfg.name} differs from "
-                         f"{config['name']}.json: {bad}")
-    return cfg
-
-
 def pick(records: list, seed: int) -> list[int]:
     """The requests the reference will read, drawn from the seed among
     ``records`` (the finished ones): the longest (prompt + output) first,
@@ -123,8 +106,10 @@ def served(records: dict, finished: dict, rids) -> list:
 
 
 def prepare(cell, *, require_tpu: bool = True, compile_cache: bool = True):
-    """Device check, compile cache, and the program's model config.
-    Returns (program config, shapes, devices)."""
+    """Device check, compile cache, the cell's architecture module and the
+    program's model config as the configuration file states it (the module
+    refuses one that differs from the file).  Returns (architecture module,
+    program config, devices)."""
     import jax
 
     devices = check_device(cell.chips) if require_tpu else jax.devices()
@@ -133,13 +118,20 @@ def prepare(cell, *, require_tpu: bool = True, compile_cache: bool = True):
     from repro.kernels.tiled_matmul.ops import kernel_mode
     if require_tpu and kernel_mode() != "pallas":
         raise NoDevice(f"kernel mode {kernel_mode()!r}, not 'pallas'")
-    return program_config(cell.config), costs.Shapes.of(cell.config), devices
+    from repro.configs import get_config
+
+    arch = spec.architecture(cell)
+    config = cell.config
+    cfg = get_config(config["model"]).replace(**config["overrides"])
+    arch.check_config(cfg, config)
+    return arch, cfg, devices
 
 
-def scheduler(cell, cfg, shapes, seed: int):
+def scheduler(cell, arch, cfg, seed: int):
     """The seed's weights, built on the device, behind a fresh scheduler."""
     t = serve.clock()
-    params = weights.program_params(weights.build(seed, shapes), shapes)
+    shapes = arch.Shapes.of(cell.config)
+    params = arch.program_params(arch.build(seed, shapes), shapes)
     log(f"weights built in {serve.clock() - t:.3f} s")
     return serve.make_scheduler(params, cfg, cell.config["serving"])
 
@@ -177,11 +169,11 @@ def with_serving(cell, **serving):
     return dataclasses.replace(cell, config=config)
 
 
-def check(cell, seed: int, shapes, seqs, controls=()) -> dict:
+def check(cell, arch, seed: int, seqs, controls=()) -> dict:
     """The reference's readings over ``seqs``, with fresh weights from the
     seed (the program's are freed by now)."""
     ref = spec.reference_module(cell)
-    w = weights.build(seed, shapes)
+    w = arch.build(seed, arch.Shapes.of(cell.config))
     try:
         return ref.readings(w, cell.config["shapes"],
                             cell.config["serving"]["max_len"], seqs,
@@ -222,16 +214,17 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     the program's place and judged as the program's is."""
     import jax
 
-    cfg, shapes, devices = prepare(cell, require_tpu=require_tpu,
-                                   compile_cache=compile_cache)
-    sched = scheduler(cell, cfg, shapes, seed)
+    arch, cfg, devices = prepare(cell, require_tpu=require_tpu,
+                                 compile_cache=compile_cache)
+    sched = scheduler(cell, arch, cfg, seed)
+    vocab = arch.Shapes.of(cell.config).vocab
     took = serve.warm_up(sched, cell.traffic, cell.config["serving"],
-                         shapes.vocab, seed)
+                         vocab, seed)
     log(f"warm-up {took:.3f} s")
     dev = devices[0]
     peaks = (spec.peaks(cell.bench_dir, dev.device_kind) if require_tpu
              else None)
-    reqs = traffic.generate(cell.traffic, seconds, seed, shapes.vocab)
+    reqs = traffic.generate(cell.traffic, seconds, seed, vocab)
 
     trace_dir = os.path.join(out_dir or os.path.join(str(root),
                                                      ".bench_out"),
@@ -256,7 +249,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     del sched
     gc.collect()
 
-    run_ = stats.Run(cell, shapes, peaks, seconds, setup_s, window)
+    run_ = stats.Run(cell, arch, peaks, seconds, setup_s, window)
     if trace:
         run_.events = devtrace.load(trace_dir)
         run_.trace_bounds = devtrace.bounds(run_.events)
@@ -272,7 +265,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         f"{np.median(lat) * 1e3:.3f} ms, max {lat.max() * 1e3:.3f} ms; "
         f"programs lowered after warm-up: {compiles['n']}")
 
-    readings = check(cell, seed, shapes, seqs, controls)
+    readings = check(cell, arch, seed, seqs, controls)
     log(f"reference: {len(seqs)} finished requests, {readings['tokens']} "
         f"served "
         f"tokens, widest gap per request {readings['per_request']}")

@@ -7,10 +7,33 @@ entries only:
 
   configs/<config>.json      sizes, serving geometry, source, reference
   references/<module>.py     the configuration's plain reference forward
+  architectures/<module>.py  the harness's part of that architecture
   traffic/<mix>.json         loop, rate or clients, length distributions
   limits/<cell>.json         the limit of each number ``correct`` compares
   metrics/<metric>.py        one reader per per-layer metric
   peaks.json                 the chip's peaks, keyed by ``device_kind``
+
+A configuration's ``reference`` key names both of its per-architecture
+modules.  ``references/<module>.py`` is the plain forward that decides
+``correct``.  ``architectures/<module>.py`` holds all that the harness
+knows of the block's structure; nothing else in ``bench/`` does, so a
+configuration of a new architecture is added as these two new files and
+data.  It gives:
+
+  Shapes, Shapes.of(config)        the block's sizes from the file's
+                                   ``shapes`` (hashable; has ``vocab``)
+  check_config(cfg, config)        refuses a program config that runs
+                                   another block or other widths
+  build(seed, shapes)              the seed's weights as plain arrays, on
+                                   the device, in their served form (what
+                                   the reference reads)
+  program_params(weights, shapes)  those arrays in the program's layout
+  prefill_ops(shapes, start, end)  operations of a prefill of positions
+                                   ``start..end-1``, the used head row in
+  decode_ops(shapes, ctx)          operations of one decode token
+                                   attending ``ctx`` keys
+  decode_tick_bytes(shapes, ctxs)  least HBM bytes of a decode tick whose
+                                   live rows attend ``ctxs`` keys
 """
 from __future__ import annotations
 
@@ -18,6 +41,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import sys
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent.parent
 ROOT = BENCH_DIR.parent
@@ -81,22 +105,39 @@ def _load_module(path: pathlib.Path, name: str):
     if spec is None or not path.is_file():
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    # registered only while it runs: a dataclass looks its module up by
+    # name as the class is made; each load is a fresh module
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    finally:
+        sys.modules.pop(name, None)
     return mod
+
+
+def _named_module(bench_dir: pathlib.Path, kind: str, name: str):
+    """``<kind>/<name>.py`` under ``bench_dir``."""
+    return _load_module(pathlib.Path(bench_dir) / kind / f"{name}.py",
+                        f"bench_{kind}_{name}")
 
 
 def metric_reader(bench_dir: pathlib.Path, name: str):
     """``metrics/<name>.py``'s ``read(run) -> float | None``."""
-    path = pathlib.Path(bench_dir) / "metrics" / f"{name}.py"
-    return _load_module(path, f"bench_metric_{name}").read
+    return _named_module(bench_dir, "metrics", name).read
 
 
 def reference_module(cell: Cell):
     """The plain reference the configuration names
     (``references/<reference>.py``)."""
-    ref = cell.config["reference"]
-    return _load_module(cell.bench_dir / "references" / f"{ref}.py",
-                        f"bench_reference_{ref}")
+    return _named_module(cell.bench_dir, "references",
+                         cell.config["reference"])
+
+
+def architecture(cell: Cell):
+    """The harness's part of the configuration's architecture
+    (``architectures/<reference>.py``)."""
+    return _named_module(cell.bench_dir, "architectures",
+                         cell.config["reference"])
 
 
 def peaks(bench_dir: pathlib.Path, device_kind: str) -> dict:

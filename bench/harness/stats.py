@@ -11,7 +11,7 @@ import numpy as np
 class Run:
     """Everything a metric reader may read of one run."""
     cell: object                 # spec.Cell
-    shapes: object               # costs.Shapes
+    arch: object                 # the cell's architecture module
     peaks: dict
     seconds: float
     setup_s: float
@@ -19,6 +19,11 @@ class Run:
     events: list | None = None   # devtrace.Event of the traced slice
     trace_bounds: tuple | None = None    # (lo, hi) on the trace's clock
     trace_host: tuple | None = None      # (start, end) on the host clock
+
+    @property
+    def shapes(self):
+        """The cell's sizes, as its architecture module reads them."""
+        return self.arch.Shapes.of(self.cell.config)
 
 
 def pct(xs, q: float) -> float | None:

@@ -3,7 +3,8 @@
 Straightforward ``jax.numpy`` at ``HIGHEST`` matmul precision, with no
 kernel, page, batch or cache layout of the program: it imports nothing
 from ``src/repro`` and is given only the seed's weights as plain arrays
-(``harness.weights.build``).  It follows the published block:
+(``build`` of ``architectures/dense_gqa.py``).  It follows the published
+block:
 
   x = embed[token]
   per layer:  x += O(attn(rope(Q(n1(x))), rope(K(n1(x))), V(n1(x))))

@@ -42,16 +42,17 @@ def main(argv=None) -> int:
     from harness import runner, serve, spec, stats, traffic
 
     cell = spec.load_cell(args.workload)
-    cfg, shapes, _ = runner.prepare(cell)
+    arch, cfg, _ = runner.prepare(cell)
+    vocab = arch.Shapes.of(cell.config).vocab
     warm = False
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(cell.traffic, rate_per_s=rate)
-        sched = runner.scheduler(cell, cfg, shapes, args.seed)
+        sched = runner.scheduler(cell, arch, cfg, args.seed)
         if not warm:
-            serve.warm_up(sched, mix, cell.config["serving"], shapes.vocab,
+            serve.warm_up(sched, mix, cell.config["serving"], vocab,
                           args.seed)
             warm = True
-        reqs = traffic.generate(mix, args.seconds, args.seed, shapes.vocab)
+        reqs = traffic.generate(mix, args.seconds, args.seed, vocab)
         at_open = {}
 
         def opened():
@@ -63,7 +64,7 @@ def main(argv=None) -> int:
         at_close = {"queue": len(sched.queue), "live": sched.n_active}
         del sched
         gc.collect()
-        run = stats.Run(dataclasses.replace(cell, traffic=mix), shapes,
+        run = stats.Run(dataclasses.replace(cell, traffic=mix), arch,
                         None, args.seconds, 0.0, w)
         due = sorted(stats.due_in_window(run), key=lambda r: r.due)
         half = max(1, len(due) // 2)

@@ -29,6 +29,19 @@ TINY_CONFIG = {
                 "prefill_chunk": 32, "bucket": 32, "max_len": 512,
                 "kv_dtype": "bfloat16"},
     "reference": "dense_gqa"}
+# the chatglm3 block at toy widths: partial RoPE, untied head, g 4
+TINY_GLM_CONFIG = {
+    "name": "tiny-glm", "model": "chatglm3_6b",
+    "overrides": {"quant_proj": "w8a8", "dtype": "bfloat16", "n_layers": 2,
+                  "d_model": 64, "n_heads": 8, "n_kv_heads": 2,
+                  "head_dim": 16, "d_ff": 128, "vocab_size": 256,
+                  "norm_eps": 1e-05},
+    "shapes": {"layers": 2, "d_model": 64, "n_heads": 8, "n_kv_heads": 2,
+               "head_dim": 16, "d_ff": 128, "vocab": 256,
+               "tied_embeddings": False, "qkv_bias": True, "norm_eps": 1e-05,
+               "rope_theta": 10000.0, "rope_fraction": 0.5},
+    "serving": TINY_CONFIG["serving"],
+    "reference": "dense_gqa"}
 TINY_TRAFFIC = {
     "name": "small", "loop": "open", "rate_per_s": 8.0, "preroll_s": 1.0,
     "prompt": {"median": 40, "sigma": 0.5, "min": 8, "max": 128},
@@ -36,6 +49,9 @@ TINY_TRAFFIC = {
 # set from this size's readings (test_control): sound runs read up to
 # about 0.03, the int4 control 0.4 and more
 TINY_LIMIT = 0.15
+# the same at TINY_GLM_CONFIG (seeds 3-10 on the CPU): sound runs read up
+# to 0.107, the int4 control 0.610 and more
+TINY_GLM_LIMIT = 0.3
 
 
 def add_cell(root: pathlib.Path, name="tiny.small", config=None,
@@ -77,4 +93,12 @@ def bench_copy(tmp_path):
 def tiny(bench_copy):
     """``bench_copy`` with the tiny cell added."""
     add_cell(bench_copy)
+    return bench_copy
+
+
+@pytest.fixture
+def tiny_glm(bench_copy):
+    """``bench_copy`` with the tiny chatglm3 cell added."""
+    add_cell(bench_copy, name="tiny-glm.small", config=TINY_GLM_CONFIG,
+             limit=TINY_GLM_LIMIT)
     return bench_copy

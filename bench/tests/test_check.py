@@ -8,14 +8,14 @@ import time
 
 import numpy as np
 import pytest
-from conftest import TINY_LIMIT
+from conftest import TINY_GLM_LIMIT, TINY_LIMIT
 
 import control
 from harness import runner, serve
 
 
-def run(root, seed=20240611, seconds=2.0):
-    return runner.run("tiny.small", seed, seconds, False,
+def run(root, seed=20240611, seconds=2.0, cell="tiny.small"):
+    return runner.run(cell, seed, seconds, False,
                       time.perf_counter(), root=root,
                       bench_dir=root / "bench", require_tpu=False,
                       compile_cache=False, out_dir=str(root / "out"))
@@ -48,6 +48,21 @@ def test_controls_are_not_correct(tiny):
     for r in rows:
         assert r["kv8"]["compared"]["kv_bits_below_config"]["value"] == 8
         assert r["w4"]["compared"]["max_logit_gap"]["value"] > TINY_LIMIT
+
+
+def test_partial_rope_cell_sound_and_controls(tiny_glm):
+    """The chatglm3 block (partial RoPE, untied LM head, 4 query heads to a
+    K/V head) at toy widths: a sound run is correct, and the int4 control
+    and the int8 K/V path are not."""
+    assert run(tiny_glm, cell="tiny-glm.small")["correct"]
+    rows = list(control.read("tiny-glm.small", [3, 4, 5], 2.0,
+                             root=tiny_glm, bench_dir=tiny_glm / "bench",
+                             require_tpu=False, compile_cache=False,
+                             out_dir=str(tiny_glm / "out")))
+    assert all(r["sound"]["correct"] for r in rows)
+    assert not any(r[c]["correct"] for r in rows for c in ("w4", "kv8"))
+    sound = max(r["sound"]["value"] for r in rows)
+    assert sound < TINY_GLM_LIMIT < min(r["w4"]["value"] for r in rows)
 
 
 def test_tokens_take_the_time_of_the_step_that_emitted_them():
@@ -91,13 +106,18 @@ def _faulty(kind):
     return lambda: Faulty
 
 
+@pytest.mark.parametrize("fixture,cell,limit", [
+    ("tiny", "tiny.small", TINY_LIMIT),
+    ("tiny_glm", "tiny-glm.small", TINY_GLM_LIMIT)])
 @pytest.mark.parametrize("kind", ["state_unchanged", "half_batch",
                                   "token_altered"])
-def test_a_broken_served_path_is_not_correct(tiny, monkeypatch, kind):
+def test_a_broken_served_path_is_not_correct(request, monkeypatch, kind,
+                                             fixture, cell, limit):
+    root = request.getfixturevalue(fixture)
     monkeypatch.setattr(serve, "scheduler_class", _faulty(kind))
-    res = run(tiny)
+    res = run(root, cell=cell)
     assert not res["correct"], (kind, res["compared"])
-    assert res["compared"]["max_logit_gap"]["value"] > TINY_LIMIT
+    assert res["compared"]["max_logit_gap"]["value"] > limit
 
 
 def test_pick_holds_the_longest_request():

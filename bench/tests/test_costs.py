@@ -1,15 +1,19 @@
-"""Operation and byte counts against hand counts for both configurations."""
+"""Operation and byte counts of the dense GQA architecture module against
+hand counts for both configurations."""
 import pytest
 from conftest import BENCH
 
-from harness import costs, spec
+from harness import spec
+
+QWEN, GLM = "qwen2.5-3b-w8a8", "chatglm3-6b-w8a8"
+# both configurations name the dense GQA block
+costs = spec.architecture(spec.load_cell("qwen2.5-3b.chat"))
 
 
 def shapes(name):
-    return costs.Shapes.of(spec.read_json(BENCH / "configs" / f"{name}.json"))
-
-
-QWEN, GLM = "qwen2.5-3b-w8a8", "chatglm3-6b-w8a8"
+    config = spec.read_json(BENCH / "configs" / f"{name}.json")
+    assert config["reference"] == "dense_gqa"
+    return costs.Shapes.of(config)
 
 
 def test_projection_params_by_hand():

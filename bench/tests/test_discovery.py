@@ -1,12 +1,16 @@
 """Configurations, mixes, limits and metric readers are found by name."""
 import json
+import pathlib
+import re
+import shutil
 import subprocess
 import sys
+import time
 
 import pytest
-from conftest import BENCH, ROOT, add_cell
+from conftest import BENCH, ROOT, TINY_CONFIG, add_cell
 
-from harness import spec
+from harness import runner, spec
 
 
 def test_every_cell_of_the_benchmark_loads():
@@ -104,3 +108,57 @@ def test_add_cell_helper_is_files_only(bench_copy):
     add_cell(bench_copy)
     after = {p for p in (bench_copy / "bench").rglob("*") if p.is_file()}
     assert before <= after
+
+
+def test_a_new_architecture_is_added_as_files_only(bench_copy, monkeypatch):
+    """A configuration whose ``reference`` names a module that is new, with
+    its architecture module and its reference added as new files: ``spec``
+    loads it, and a whole run goes through it (weights for the program
+    and for the check alike)."""
+    bench = bench_copy / "bench"
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    for kind in ("architectures", "references"):
+        shutil.copy(bench / kind / "dense_gqa.py",
+                    bench / kind / "dense_copy.py")
+    with open(bench / "architectures" / "dense_copy.py", "a") as f:
+        f.write("\n\nBUILT = []\n_plain_build = build\n\n\n"
+                "def build(seed, s):\n    BUILT.append(seed)\n"
+                "    return _plain_build(seed, s)\n")
+    add_cell(bench_copy, name="copy.small",
+             config=dict(TINY_CONFIG, name="copy", reference="dense_copy"))
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+    cell = spec.load_cell("copy.small", bench_copy, bench)
+    arch = spec.architecture(cell)
+    assert pathlib.Path(arch.__file__).name == "dense_copy.py"
+    assert hasattr(spec.reference_module(cell), "readings")
+
+    seen = []
+    monkeypatch.setattr(spec, "architecture",
+                        lambda c: seen.append(c.config["reference"]) or arch)
+    res = runner.run_cell(cell, 7, 2.0, False, time.perf_counter(),
+                          root=bench_copy, require_tpu=False,
+                          compile_cache=False,
+                          out_dir=str(bench_copy / "out"))
+    assert res["correct"], res["compared"]
+    assert res["attempted"] > 0
+    assert seen == ["dense_copy"] and arch.BUILT == [7, 7]
+
+
+# what only a dense block has: its projections, FFN and norm kinds and FFN
+# width, as code names them (quoted, or as an attribute)
+DENSE = "wq|wk|wv|wo|gate|up|down|swiglu|rmsnorm|d_ff"
+DENSE_CODE = re.compile(rf"""["']({DENSE})["']|\.({DENSE})\b""")
+
+
+def test_only_architecture_modules_know_the_block():
+    """The harness, the readers and the scripts reach the block's structure
+    only through the cell's architecture module."""
+    files = [*BENCH.glob("*.py"), *(BENCH / "harness").glob("*.py"),
+             *(BENCH / "metrics").glob("*.py")]
+    assert len(files) > 20
+    for path in files:
+        found = DENSE_CODE.search(path.read_text())
+        assert found is None, (path.name, found.group(0))
+    assert DENSE_CODE.search((BENCH / "architectures" / "dense_gqa.py")
+                             .read_text())
